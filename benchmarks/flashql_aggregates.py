@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from _harness import REPS, interleaved_best_of
+from repro.compile_cache import enable_compile_cache
 from repro.kernels.popcount import popcount
 from repro.query import (
     BatchScheduler,
@@ -173,4 +174,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,7 @@ import sys
 import numpy as np
 
 from _harness import interleaved_best_of
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     BatchScheduler,
     BitmapStore,
@@ -180,4 +181,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ import sys
 import numpy as np
 
 from _harness import REPS, interleaved_best_of
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     BatchScheduler,
     BitmapStore,
@@ -190,4 +191,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

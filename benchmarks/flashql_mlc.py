@@ -28,6 +28,7 @@ from itertools import combinations
 import numpy as np
 
 from _harness import REPS, interleaved_best_of
+from repro.compile_cache import enable_compile_cache
 from repro.core.placement import Layout
 from repro.query import (
     AtLeast,
@@ -58,9 +59,7 @@ def make_table(rng, n):
 def build(table, levels, reserve_rows=0):
     store = BitmapStore()
     store.ingest(table, reserve_rows=reserve_rows)
-    dev = FlashDevice(
-        num_planes=4, interpret=True, layout=Layout(levels=levels)
-    )
+    dev = FlashDevice(num_planes=4, layout=Layout(levels=levels))
     programs, words = store.program(dev)
     sch = BatchScheduler(dev, store)
     return sch, programs, words
@@ -218,4 +217,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -34,6 +34,7 @@ import sys
 import numpy as np
 
 from _harness import REPS, interleaved_best_of, latency_summary, timed
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Avg,
     Count,
@@ -221,4 +222,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

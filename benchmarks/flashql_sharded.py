@@ -34,6 +34,7 @@ import sys
 import numpy as np
 
 from _harness import REPS, interleaved_best_of
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Agg,
     BatchScheduler,
@@ -214,4 +215,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -26,6 +26,7 @@ import numpy as np
 
 from _harness import REPS, interleaved_best_of
 from flashql_pipeline import build_queries, check_exact
+from repro.compile_cache import enable_compile_cache
 from repro.query import Mask, build_sharded_flashql, validate_trace
 
 NUM_SHARDS = 4
@@ -139,4 +140,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -24,6 +24,7 @@ import numpy as np
 
 from _harness import interleaved_best_of
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.engine import FlashArray
 from repro.core.planner import Planner
 from repro.kernels.popcount import popcount
@@ -148,4 +149,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

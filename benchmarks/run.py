@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from benchmarks.paper_figs import (
@@ -56,4 +58,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bitops import BitOp
-from repro.kernels.mws import mws_reduce, parabit_reduce
+from repro.kernels.mws import parabit_reduce
+from repro.kernels.mws.mws import mws_block_reduce_pallas
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.popcount import popcount
 from repro.kernels.signcomp import compress_signs
 
@@ -42,7 +44,13 @@ def mws_vs_parabit():
     W = 1 << 16
     for n in (2, 4, 8, 16, 32, 48, 64):
         x = jnp.array(rng.integers(0, 2**32, (n, W), dtype=np.uint32))
-        t_fused = _time(lambda a: mws_reduce(a, BitOp.AND), x)
+        # the served MWS kernel: one NAND block of n wordlines
+        t_fused = _time(
+            lambda a: mws_block_reduce_pallas(
+                a[None], or_blocks=True, interpret=resolve_interpret(None)
+            ),
+            x,
+        )
         t_serial = _time(lambda a: parabit_reduce(a, BitOp.AND), x)
         fused_b, serial_b = hbm_traffic_model(n, W)
         rows.append(

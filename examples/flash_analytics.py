@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/flash_analytics.py
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.engine import FlashArray
 from repro.core.expr import Page, and_, or_
 from repro.flashsim import (
@@ -88,6 +89,7 @@ def projection():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     bmi_demo()
     kcs_demo()
     projection()

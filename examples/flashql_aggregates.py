@@ -15,6 +15,7 @@ Run:  PYTHONPATH=src python examples/flashql_aggregates.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Avg,
     BatchScheduler,
@@ -101,4 +102,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

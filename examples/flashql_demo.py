@@ -10,6 +10,7 @@ Run:  PYTHONPATH=src python examples/flashql_demo.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Agg,
     BatchScheduler,
@@ -85,4 +86,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ Run:  PYTHONPATH=src python examples/flashql_hdc.py
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.bitops import pack_bits, unpack_bits
 from repro.core.engine import FlashArray
 from repro.core.expr import Page, Threshold, xor_
@@ -114,4 +115,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.query import Agg, Eq, In, Query, Range, build_sharded_flashql
 from repro.query.ast import and_ as qand
 
@@ -96,4 +97,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

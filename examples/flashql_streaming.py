@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Count,
     Eq,
@@ -82,4 +83,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

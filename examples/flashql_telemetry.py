@@ -12,6 +12,7 @@ Run:  PYTHONPATH=src python examples/flashql_telemetry.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.query import (
     Avg,
     Count,
@@ -108,4 +109,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

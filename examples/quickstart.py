@@ -12,11 +12,12 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.bitops import BitOp
 from repro.core.engine import FlashArray, eval_expr
 from repro.core.expr import Page, and_, or_
 from repro.core.planner import Planner
-from repro.kernels.mws import mws_reduce, parabit_reduce
+from repro.kernels.mws import parabit_reduce
 from repro.kernels.popcount import popcount
 
 
@@ -62,9 +63,10 @@ def main():
     plan_or = Planner(arr2.layout).compile(or_(*map(Page, ors)))
     print(f"32-operand OR  -> {plan_or.num_sensing_ops} sensing operation(s)")
     got = arr2.execute(plan_or)
-    assert (got == mws_reduce(jnp.stack([logical[n] for n in ors]), BitOp.OR)).all()
+    assert (got == eval_expr(or_(*map(Page, ors)), logical)).all()
     print("De Morgan inverse-storage OR: OK")
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

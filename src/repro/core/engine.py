@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bitops import BitOp
 from repro.core.commands import (
     CommandPlan,
     ESPCommand,
@@ -54,7 +53,8 @@ from repro.core.reliability import (
     rber,
 )
 from repro.core.store import IDENTITY_SLOT, PackedStore
-from repro.kernels.mws import mws_reduce
+from repro.kernels.mws.mws import mws_block_reduce_pallas
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.threshold import bitslice_threshold, threshold_reduce
 
 
@@ -67,42 +67,46 @@ def _stable_seed(name: str) -> int:
     return zlib.crc32(name.encode("utf-8")) & 0xFFFF
 
 
+def _and_blocks(cube: jax.Array) -> jax.Array:
+    """``(blocks, words)``: AND over each block's wordlines (XLA fold)."""
+    anded = cube[:, 0]
+    for i in range(1, cube.shape[1]):
+        anded = anded & cube[:, i]
+    return anded
+
+
+def _or_rows(rows: jax.Array) -> jax.Array:
+    raw = rows[0]
+    for b in range(1, rows.shape[0]):
+        raw = raw | rows[b]
+    return raw
+
+
 def fused_block_reduce(
-    cube: jax.Array, inverse: bool, *, interpret: bool = True
+    cube: jax.Array, inverse: bool, *, interpret: bool | None = None
 ) -> jax.Array:
     """MWS semantics on a gathered ``(blocks, wordlines, words)`` cube.
 
-    AND over the wordline axis of every block in ONE fused ``mws_reduce``
-    dispatch (blocks ride along the word axis, so all planes and blocks are
-    covered at once), then OR across blocks in a second dispatch;
+    AND over the wordline axis of every block, then OR across blocks;
     ``inverse`` complements the result (inverse-read mode).  Rows padded
     with the all-ones identity are AND-neutral.
 
-    With ``interpret=True`` (no TPU) the Pallas interpreter's ~ms/call
-    overhead would dominate query serving, so emulation folds with plain
-    XLA ops instead — bit-identical to the kernel (the kernel tests assert
-    exactly that) and efficient under ``jax.vmap``; on real hardware
-    (``interpret=False``) the fused Pallas kernel is dispatched.
+    On a TPU both stages run in ONE Pallas dispatch over the cube
+    (:func:`mws_block_reduce_pallas`); elsewhere (see
+    :mod:`repro.kernels.platform`) the emulation folds with plain XLA ops —
+    bit-identical to the kernel (the kernel tests assert exactly that) and
+    cheap under ``jax.vmap``, where the Pallas interpreter's per-call cost
+    would dominate query serving.
     """
-    k, n, w = cube.shape
-    if interpret:
-        anded = cube[:, 0]
-        for i in range(1, n):
-            anded = anded & cube[:, i]
-        raw = anded[0]
-        for b in range(1, k):
-            raw = raw | anded[b]
+    if resolve_interpret(interpret):
+        raw = _or_rows(_and_blocks(cube))
     else:
-        flat = cube.swapaxes(0, 1).reshape(n, k * w)
-        raw = mws_reduce(flat, BitOp.AND, interpret=False).reshape(k, w)
-        raw = (
-            mws_reduce(raw, BitOp.OR, interpret=False) if k > 1 else raw[0]
-        )
+        raw = mws_block_reduce_pallas(cube, or_blocks=True)
     return ~raw if inverse else raw
 
 
 def threshold_block_reduce(
-    cube: jax.Array, k: int, inverse: bool, *, interpret: bool = True
+    cube: jax.Array, k: int, inverse: bool, *, interpret: bool | None = None
 ) -> jax.Array:
     """k-of-N threshold sensing on a gathered ``(blocks, wls, words)`` cube.
 
@@ -114,21 +118,16 @@ def threshold_block_reduce(
     family/vmap shape padding can never count toward the threshold.
     ``inverse`` complements AFTER the comparison.
 
-    Like :func:`fused_block_reduce`, emulation (``interpret=True``) folds
-    with plain XLA ops — the same bit-sliced ripple-carry counter the
-    Pallas kernel runs, so both paths are bit-identical by construction —
-    while ``interpret=False`` dispatches the fused kernels.  Explicit
-    folds throughout (no ``jnp.bitwise_*.reduce``).
+    Like :func:`fused_block_reduce`, emulation folds with plain XLA ops —
+    the same bit-sliced ripple-carry counter the Pallas kernel runs, so
+    both paths are bit-identical by construction — while a TPU dispatches
+    the fused kernels.  Explicit folds throughout (no
+    ``jnp.bitwise_*.reduce``).
     """
-    kb, n, w = cube.shape
-    if interpret:
-        anded = cube[:, 0]
-        for i in range(1, n):
-            anded = anded & cube[:, i]
-        raw = bitslice_threshold(anded, k, kb)[0]
+    if resolve_interpret(interpret):
+        raw = bitslice_threshold(_and_blocks(cube), k, cube.shape[0])[0]
     else:
-        flat = cube.swapaxes(0, 1).reshape(n, kb * w)
-        anded = mws_reduce(flat, BitOp.AND, interpret=False).reshape(kb, w)
+        anded = mws_block_reduce_pallas(cube, or_blocks=False)
         raw = threshold_reduce(anded, k, interpret=False)
     return ~raw if inverse else raw
 
@@ -141,7 +140,10 @@ class FlashArray:
     store: PackedStore = field(default_factory=PackedStore)
     program_configs: dict[str, ProgramConfig] = field(default_factory=dict)
     pec: dict[int, int] = field(default_factory=dict)  # block -> P/E cycles
-    interpret: bool = True
+    # kernel path: None resolves, at construction, from the platform the
+    # store lives on (repro.kernels.platform): compiled Pallas on a TPU,
+    # emulation elsewhere
+    interpret: bool | None = None
     # names of non-ESP pages, maintained incrementally so hot paths never
     # scan program_configs (one entry per (column, value) bitmap adds up)
     _non_esp: set = field(default_factory=set, repr=False)
@@ -154,6 +156,9 @@ class FlashArray:
     # occupies and reprogramming the live data — compaction charges these
     # in the SSD projection at t_bers_ms)
     block_erases: int = 0
+
+    def __post_init__(self):
+        self.interpret = resolve_interpret(self.interpret, self.store.device)
 
     # -- host API (fc_write / fc_read, §6.3) -------------------------------
     def fc_write(
@@ -262,7 +267,7 @@ class FlashArray:
             self.pec[b] = self.pec.get(b, 0) + 1
         self.block_erases += len(blocks)
         old = self.store
-        self.store = PackedStore(planes=old.planes)
+        self.store = PackedStore(planes=old.planes, device=old.device)
         self.store.epoch = old.epoch + 1
         self.store.region_epochs = {
             r: e + 1 for r, e in old.region_epochs.items()

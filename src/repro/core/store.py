@@ -26,7 +26,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 IDENTITY_SLOT = 0  # all-ones row (AND identity / pad row), always present
@@ -62,6 +61,9 @@ class PackedStore:
     """
 
     planes: int = 1
+    # the accelerator the device snapshot lives on; None leaves it to
+    # JAX's default device (a pipelined fleet puts stripe s on device s)
+    device: jax.Device | None = None
     _slots: dict[str, int] = field(default_factory=dict)
     _buf: np.ndarray | None = None  # (capacity, planes * wpp) uint32
     _n: int = 0
@@ -180,7 +182,7 @@ class PackedStore:
 
     def __getitem__(self, name: str) -> jax.Array:
         slot = self._slots[name]
-        return jnp.asarray(self._buf[slot, : self._words])
+        return jax.device_put(self._buf[slot, : self._words], self.device)
 
     def snapshot(self) -> jax.Array:
         """Device-side ``(slots, planes * words_per_plane)`` packed array.
@@ -191,7 +193,7 @@ class PackedStore:
         """
         if self._snapshot is None:
             assert self._buf is not None, "empty store has no snapshot"
-            self._snapshot = jnp.asarray(self._buf[: self._n])
+            self._snapshot = jax.device_put(self._buf[: self._n], self.device)
             self.snapshot_uploads += 1
         return self._snapshot
 

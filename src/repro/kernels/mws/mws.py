@@ -116,3 +116,78 @@ def mws_reduce_pallas(
         interpret=interpret,
     )(stack)
     return out[0]
+
+
+def _and_rows(x_ref) -> jax.Array:
+    """AND over the rows of an ``(n, BW)`` tile, any ``n``: whole 8-row
+    sublane groups first, then the ragged tail one row at a time (the
+    wordline count of a NAND block is rarely a multiple of 8)."""
+    n = x_ref.shape[0]
+    full = n - n % 8
+    acc = None
+    for i in range(0, full, 8):
+        blk = x_ref[i : i + 8, :]
+        acc = blk if acc is None else acc & blk
+    if acc is not None:
+        acc = _tree_reduce(acc, BitOp.AND)
+    for i in range(full, n):
+        row = x_ref[i : i + 1, :]
+        acc = row if acc is None else acc & row
+    return acc  # (1, BW)
+
+
+def _block_kernel(x_ref, o_ref, *, or_blocks: bool):
+    part = _and_rows(x_ref)
+    if not or_blocks:
+        o_ref[...] = part
+        return
+    b = pl.program_id(1)  # NAND-block index (innermost => safe revisits)
+
+    @pl.when(b == 0)
+    def _init():
+        o_ref[...] = part
+
+    @pl.when(b > 0)
+    def _acc():
+        o_ref[...] = o_ref[...] | part
+
+
+@functools.partial(
+    jax.jit, static_argnames=("or_blocks", "block_words", "interpret")
+)
+def mws_block_reduce_pallas(
+    cube: jax.Array,
+    *,
+    or_blocks: bool,
+    block_words: int = DEFAULT_BLOCK_WORDS,
+    interpret: bool = False,
+) -> jax.Array:
+    """Both MWS stages over a gathered ``(blocks, wordlines, W)`` cube.
+
+    Each grid step reads one block's ``(wordlines, block_words)`` tile —
+    the whole wordline axis is one tile, so the cube is neither transposed
+    nor padded — and ANDs its rows (the NAND string).  ``or_blocks``
+    ORs the blocks into a ``(W,)`` result (the shared bitlines);
+    otherwise the per-block ANDs come back as ``(blocks, W)`` for a
+    threshold combine.  A ragged last word tile needs no padding: its
+    out-of-range lanes are never written back.
+    """
+    k, n, w = cube.shape
+    bw = min(block_words, w)  # a narrow page is one full-width tile
+    grid = (pl.cdiv(w, bw), k)
+    in_spec = pl.BlockSpec((None, n, bw), lambda j, b: (b, 0, j))
+    if or_blocks:
+        out_spec = pl.BlockSpec((1, bw), lambda j, b: (0, j))
+        out_shape = jax.ShapeDtypeStruct((1, w), cube.dtype)
+    else:
+        out_spec = pl.BlockSpec((None, 1, bw), lambda j, b: (b, 0, j))
+        out_shape = jax.ShapeDtypeStruct((k, 1, w), cube.dtype)
+    out = pl.pallas_call(
+        functools.partial(_block_kernel, or_blocks=or_blocks),
+        grid=grid,
+        in_specs=[in_spec],
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(cube)
+    return out[0] if or_blocks else out[:, 0]

@@ -20,6 +20,7 @@ from repro.kernels.mws.mws import (
     DEFAULT_FAN_IN,
     mws_reduce_pallas,
 )
+from repro.kernels.platform import resolve_interpret
 
 
 def _identity_word(op: BitOp, dtype) -> np.ndarray:
@@ -48,12 +49,14 @@ def mws_reduce(
     *,
     fan_in: int = DEFAULT_FAN_IN,
     block_words: int = DEFAULT_BLOCK_WORDS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Bitwise ``op``-reduce over axis 0 of an (N, W) packed-word stack.
 
     Handles arbitrary N/W by padding the operand axis with the reduction
     identity and the word axis with zeros, then slicing the result back.
+    ``interpret=None`` compiles the kernel on a TPU and runs the Pallas
+    interpreter elsewhere (:mod:`repro.kernels.platform`).
     """
     if stack.ndim != 2:
         raise ValueError(f"expected (N, W) stack, got {stack.shape}")
@@ -63,7 +66,11 @@ def mws_reduce(
     padded = _pad_to(stack, 0, fan_in, ident)
     padded = _pad_to(padded, 1, block_words, ident)
     out = mws_reduce_pallas(
-        padded, op, fan_in=fan_in, block_words=block_words, interpret=interpret
+        padded,
+        op,
+        fan_in=fan_in,
+        block_words=block_words,
+        interpret=resolve_interpret(interpret),
     )
     return out[:w]
 

@@ -12,7 +12,8 @@ and 3(N−1)·W+… for the serial baseline.
 
 Grid: word-blocks outer, operand-blocks inner (same revisit-safe layout as
 `kernels/mws`); a VMEM scratch block holds the running reduction, and the
-(1,1) int32 output accumulates SWAR popcounts on the final operand block.
+(1,1) int32 output — a scalar, so it lives in SMEM (the TPU refuses scalar
+stores to VMEM) — accumulates SWAR popcounts on the final operand block.
 """
 
 from __future__ import annotations
@@ -97,7 +98,7 @@ def mws_count_pallas(
         ),
         grid=(w // block_words, n // fan_in),
         in_specs=[pl.BlockSpec((fan_in, block_words), lambda j, i: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, block_words), jnp.uint32)],
         interpret=interpret,

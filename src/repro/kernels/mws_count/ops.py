@@ -19,6 +19,7 @@ from repro.kernels.mws_count.mws_count import (
     DEFAULT_FAN_IN,
     mws_count_pallas,
 )
+from repro.kernels.platform import resolve_interpret
 
 
 @functools.partial(
@@ -30,16 +31,23 @@ def mws_count(
     *,
     fan_in: int = DEFAULT_FAN_IN,
     block_words: int = DEFAULT_BLOCK_WORDS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Population count of the op-reduction of an (N, W) word stack -> ()."""
+    """Population count of the op-reduction of an (N, W) word stack -> ().
+
+    ``interpret=None`` compiles the kernel on a TPU and runs the Pallas
+    interpreter elsewhere (:mod:`repro.kernels.platform`)."""
     n, w = stack.shape
     fan_in = min(fan_in, max(8, 8 * -(-n // 8)))
     ident = _identity_word(op, stack.dtype)
     padded = _pad_to(stack, 1, block_words, 0)  # word axis: zeros
     padded = _pad_to(padded, 0, fan_in, ident)  # operand axis: identity
     count = mws_count_pallas(
-        padded, op, fan_in=fan_in, block_words=block_words, interpret=interpret
+        padded,
+        op,
+        fan_in=fan_in,
+        block_words=block_words,
+        interpret=resolve_interpret(interpret),
     )
     if op.inverted:
         padded_words = padded.shape[1] - w

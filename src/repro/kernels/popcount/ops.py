@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.popcount.popcount import (
     DEFAULT_BLOCK_ROWS,
     DEFAULT_BLOCK_WORDS,
@@ -22,22 +23,21 @@ def popcount(
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     block_words: int = DEFAULT_BLOCK_WORDS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Population count per row. (W,)->() or (R, W)->(R,); zero-pads freely
     (padding words contribute 0 to the count).
 
-    With ``interpret=True`` (no TPU) the Pallas interpreter walks the grid
-    in Python — milliseconds per block, which would dominate batched
-    aggregation — so emulation counts with plain XLA ops instead
-    (bit-identical to the kernel: the kernel tests assert exactly that);
-    on real hardware (``interpret=False``) the Pallas kernel is
-    dispatched.
+    Emulation (the default off a TPU, see :mod:`repro.kernels.platform`)
+    counts with plain XLA ops: the Pallas interpreter walks the grid in
+    Python — milliseconds per block, which would dominate batched
+    aggregation — and the XLA count is bit-identical to the kernel (the
+    kernel tests assert exactly that).  A TPU dispatches the Pallas kernel.
     """
     squeeze = words.ndim == 1
     if squeeze:
         words = words[None]
-    if interpret:
+    if resolve_interpret(interpret):
         out = jnp.sum(
             jax.lax.population_count(words).astype(jnp.int32), axis=-1
         )
@@ -51,6 +51,6 @@ def popcount(
         padded,
         block_rows=block_rows,
         block_words=block_words,
-        interpret=interpret,
+        interpret=False,
     )[:r]
     return out[0] if squeeze else out

@@ -12,6 +12,7 @@ import functools
 import jax
 
 from repro.kernels.mws.ops import _pad_to
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.threshold.threshold import (
     DEFAULT_BLOCK_WORDS,
     threshold_pallas,
@@ -26,9 +27,12 @@ def threshold_reduce(
     k: int,
     *,
     block_words: int = DEFAULT_BLOCK_WORDS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Per-bit ``count-of-set-rows >= k`` over an (N, W) word stack -> (W,)."""
+    """Per-bit ``count-of-set-rows >= k`` over an (N, W) word stack -> (W,).
+
+    ``interpret=None`` compiles the kernel on a TPU and runs the Pallas
+    interpreter elsewhere (:mod:`repro.kernels.platform`)."""
     n, w = anded.shape
     if not 1 <= k <= n:
         raise ValueError(f"threshold k={k} outside 1..{n} rows")
@@ -36,6 +40,6 @@ def threshold_reduce(
     padded = _pad_to(anded, 1, bw, 0)  # word axis: zeros
     padded = _pad_to(padded, 0, 8, 0)  # block axis: zeros (never count)
     out = threshold_pallas(
-        padded, k, n, block_words=bw, interpret=interpret
+        padded, k, n, block_words=bw, interpret=resolve_interpret(interpret)
     )
     return out[:w]

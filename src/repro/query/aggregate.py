@@ -61,7 +61,7 @@ from repro.query.bitmap import BitmapStore, bsi_pages, eq_pages, fetch_pages
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sliced_counts(
-    masks: jax.Array, extras: jax.Array, *, interpret: bool
+    masks: jax.Array, extras: jax.Array, *, interpret: bool | None
 ) -> jax.Array:
     """``(B, P)`` popcounts of ``mask ∧ page`` for every member × page.
 
@@ -77,7 +77,7 @@ def sliced_counts(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sliced_counts_with_total(
-    masks: jax.Array, extras: jax.Array, *, interpret: bool
+    masks: jax.Array, extras: jax.Array, *, interpret: bool | None
 ) -> jax.Array:
     """``(B, P+1)``: per-slice popcounts plus the plain mask popcount in
     the last column (AVG's numerator slices + denominator, one dispatch)."""
@@ -119,7 +119,7 @@ def pervalue_counts(
     *,
     groups: int,
     bits: int,
-    interpret: bool,
+    interpret: bool | None,
 ):
     """Per-group popcounts for TOP-K / GROUP BY.
 
@@ -182,7 +182,7 @@ class Aggregator:
         return ()
 
     # -- batched device-side reduce
-    def batch_reduce(self, masks, extras, sig: tuple, *, interpret: bool):
+    def batch_reduce(self, masks, extras, sig: tuple, *, interpret: bool | None):
         """Reduce ``(B, W)`` result bitmaps (+ ``(B, P, W)`` extra planes)
         to per-member device values; one jit'd dispatch per group.  ``sig``
         is the group's :meth:`reduce_sig` (static shape info).
@@ -600,7 +600,7 @@ _BY_KIND: dict[str, Aggregator] = {
 }
 
 
-def kind_reduce(kind: str, masks, extras, sig: tuple, *, interpret: bool):
+def kind_reduce(kind: str, masks, extras, sig: tuple, *, interpret: bool | None):
     """Run one reduce group's device computation from its static group key
     — the traced body :func:`repro.query.device.make_flush_runner` inlines
     per reduce group of a fused flush program."""
@@ -760,7 +760,7 @@ def reduce_flush(
     stores: list[BitmapStore],
     store_keys: list,
     *,
-    interpret: bool,
+    interpret: bool | None,
     extras_cache: dict,
     cache_cap: int = 128,
 ) -> tuple[list, list[int], int]:

@@ -95,6 +95,24 @@ def fetch_pages(store: "BitmapStore", names: tuple[str, ...]) -> jax.Array:
     return jnp.stack([store.logical[n] for n in names])
 
 
+def distinct_values(vals: np.ndarray) -> np.ndarray:
+    """Sorted distinct values of a column of non-negative integers.
+
+    Bitmap-indexed columns have few distinct values, so the sort behind
+    ``np.unique`` is avoided: a range of at most two values is just its
+    min and max, and a small range takes one presence pass over the rows.
+    """
+    if vals.size and vals.dtype.kind in "ui":
+        lo, hi = int(vals.min()), int(vals.max())
+        if 0 <= lo and hi - lo <= 1:  # e.g. a 0/1 column: both present
+            return np.unique(np.array([lo, hi], vals.dtype))
+        if 0 <= lo and hi < (1 << 16):
+            present = np.zeros((hi + 1,), bool)
+            present[vals] = True
+            return np.flatnonzero(present).astype(vals.dtype)
+    return np.unique(vals)
+
+
 @dataclass(frozen=True)
 class ColumnIndex:
     """Per-column metadata the compiler lowers predicates against."""
@@ -267,16 +285,17 @@ class BitmapStore:
             vals = np.asarray(raw)
             if n and vals.min() < 0:
                 raise ValueError(f"column {col!r} has negative values")
+            present = distinct_values(vals)
             if schema is not None:
                 distinct = np.asarray(schema[col])
-                missing = np.setdiff1d(vals, distinct)
+                missing = np.setdiff1d(present, distinct)
                 if missing.size:
                     raise ValueError(
                         f"column {col!r} has values {missing[:5]} outside "
                         "the forced schema"
                     )
             else:
-                distinct = np.unique(vals)
+                distinct = present
             bits = (
                 max(int(distinct[-1]).bit_length(), 1)
                 if distinct.size
@@ -286,20 +305,25 @@ class BitmapStore:
                 col, tuple(int(v) for v in distinct), bits
             )
             self.column_epochs[col] = self.column_epochs.get(col, 0) + 1
+            # Python-int operands keep the comparisons in the column's
+            # own (often uint8) dtype: no widened copy of a large column
             for v in distinct:
-                bitsarr = (vals == v).astype(np.uint8)
-                self.logical[eq_page(col, int(v))] = self._pack(bitsarr)
+                self.logical[eq_page(col, int(v))] = self._pack(
+                    vals == int(v)
+                )
             for b in range(bits):
-                slice_bits = ((vals >> b) & 1).astype(np.uint8)
-                self.logical[bsi_page(col, b)] = self._pack(slice_bits)
+                self.logical[bsi_page(col, b)] = self._pack(
+                    (vals & (1 << b)) != 0
+                )
 
     def _pack(self, bits: np.ndarray) -> np.ndarray:
-        """Pack a row-bit array into a host page of ``self.words`` words
-        (LSB-first per word, little word order — same convention as
-        :func:`repro.core.bitops.pack_bits`)."""
-        span = np.zeros((self.words * WORD_BITS,), np.uint8)
-        span[: bits.shape[0]] = bits
-        return np.packbits(span, bitorder="little").view(np.uint32).copy()
+        """Pack a row-bit array (nonzero = set) into a host page of
+        ``self.words`` words (LSB-first per word, little word order — same
+        convention as :func:`repro.core.bitops.pack_bits`)."""
+        packed = np.packbits(bits, bitorder="little")
+        page = np.zeros((self.words * (WORD_BITS // 8),), np.uint8)
+        page[: packed.size] = packed
+        return page.view(np.uint32)
 
     # -- incremental ingest --------------------------------------------------
     def check_append(self, rows: dict[str, np.ndarray]) -> int:

@@ -623,6 +623,20 @@ class FlushProgram:
             self.cse_idxs,
         )
 
+    def lower(self, data, mask):
+        """The fused program lowered for ``data`` / ``mask`` (arrays or
+        ``jax.ShapeDtypeStruct``s): ``.compile()`` gives its HLO text and
+        memory analysis."""
+        return self.runner.lower(
+            data,
+            self.group_idxs,
+            self.inv_perm,
+            mask,
+            self.sels,
+            self.extras,
+            self.cse_idxs,
+        )
+
     def unpack(self, flat: np.ndarray, aggs: list) -> list:
         """Payload words -> per-member partials (one host transfer's worth).
 
@@ -647,7 +661,7 @@ def compile_flush(
     store_keys: list,
     *,
     words: int,
-    interpret: bool,
+    interpret: bool | None,
     runner_cache: dict,
     extras_cache: dict,
     pad: bool = True,
@@ -733,12 +747,12 @@ def compile_flush(
     # interpret is baked into the traced program, so it joins the cache
     # key: a (hand-built) fleet mixing interpret modes must not share
     # runners across its devices
-    rkey = key + (bool(interpret),)
+    rkey = key + (interpret,)
     runner = runner_cache.get(rkey)
     if runner is None:
         if len(runner_cache) >= 128:  # jitted programs hold executables
             runner_cache.clear()
-        runner = make_flush_runner(key, bool(interpret))
+        runner = make_flush_runner(key, interpret)
         runner_cache[rkey] = runner
     return FlushProgram(
         key=key,

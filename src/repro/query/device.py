@@ -7,7 +7,8 @@ Python loop over commands.  ``FlashDevice`` extends it for query serving:
   :class:`repro.core.store.PackedStore`) — a logical bit vector is striped
   across ``num_planes`` planes exactly like the paper's SSD stripes a
   800M-user bitmap, and because planes are word-axis shards, ONE fused
-  ``mws_reduce`` dispatch senses a command on every plane at once;
+  block-reduce dispatch (:func:`repro.core.engine.fused_block_reduce`)
+  senses a command on every plane at once;
 * a :class:`CommandPlan` compiles to an :class:`ExecPlan`: per MWS command
   a static ``(blocks, wordlines)`` slot-index array (ragged wordline sets
   padded with the store's all-ones identity slot) plus the static ISCM
@@ -214,7 +215,7 @@ def reorder_rows(pieces: list[jax.Array], order: list[int]) -> jax.Array:
     return allout[jnp.asarray(inv)]
 
 
-def plan_step_fn(signature: tuple[_Step, ...], interpret: bool):
+def plan_step_fn(signature: tuple[_Step, ...], interpret: bool | None):
     """Pure single-plan executor for one signature:
     ``run_one(data, shared, *idxs)``.
 
@@ -235,7 +236,9 @@ def plan_step_fn(signature: tuple[_Step, ...], interpret: bool):
         it = iter(idxs)
         for st in signature:
             if st.kind == "mws":
-                cube = data[next(it)]  # (blocks, wordlines, words)
+                # (blocks, wordlines, words); take, not data[idx]: it
+                # traces several times cheaper inside vmapped programs
+                cube = jnp.take(data, next(it), axis=0, mode="clip")
                 for bi, wi, o in st.subs:
                     cube = cube.at[bi, wi].set(scratch[o])
                 for bi, wi, k in st.shared:
@@ -270,7 +273,7 @@ def plan_step_fn(signature: tuple[_Step, ...], interpret: bool):
 
 def make_plan_runner(
     signature: tuple[_Step, ...],
-    interpret: bool,
+    interpret: bool | None,
     *,
     shard_data: bool = False,
 ):
@@ -301,7 +304,7 @@ def make_plan_runner(
     )
 
 
-def make_flush_runner(key: tuple, interpret: bool):
+def make_flush_runner(key: tuple, interpret: bool | None):
     """Build the single jitted program executing a whole flush signature.
 
     ``key`` is the flush signature: ``(sense, reduce, w, cse)`` where
@@ -378,13 +381,16 @@ class FlashDevice(FlashArray):
     telemetry: object = field(default=None, repr=False)
 
     def __post_init__(self):
+        super().__post_init__()
         if self.store.planes != self.num_planes:
             if len(self.store):
                 raise ValueError(
                     "cannot re-stripe a non-empty store; construct the "
                     "device with store=PackedStore(planes=num_planes)"
                 )
-            self.store = PackedStore(planes=self.num_planes)
+            self.store = PackedStore(
+                planes=self.num_planes, device=self.store.device
+            )
 
     def reset_after_rebuild(self) -> None:
         """Drop prepared-batch state after :meth:`erase_rebuild`.

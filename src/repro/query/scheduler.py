@@ -357,6 +357,9 @@ class BatchScheduler:
     # jitted runners per flush signature — see compile_flush
     _flush_programs: dict = field(default_factory=dict, repr=False)
     _runner_cache: dict = field(default_factory=dict, repr=False)
+    # the fused program the last flush ran (FlushProgram.lower exposes its
+    # HLO and memory analysis); None until a fused flush has run
+    last_program: object = field(default=None, repr=False)
     # flush-level CSE rewrites per (batch composition, store epochs) —
     # see repro.query.optimize.cse_flush
     _cse_cache: dict = field(default_factory=dict, repr=False)
@@ -754,6 +757,7 @@ class BatchScheduler:
                     shared_execs=() if cse is None else cse.shared_execs,
                 )
                 self._flush_programs[key] = program
+            self.last_program = program
             payload = program.run(self.device.store.snapshot(), mask_words)
             if cse is None:
                 age_spill_blocks(self.device.pec, execs)

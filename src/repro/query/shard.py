@@ -74,6 +74,7 @@ import numpy as np
 
 from repro.core.bitops import num_words as _num_words
 from repro.core.placement import Layout
+from repro.core.store import PackedStore
 from repro.flashsim.geometry import DEFAULT_SSD, SSDConfig
 from repro.query.aggregate import (
     get_aggregator,
@@ -82,7 +83,7 @@ from repro.query.aggregate import (
     validate_query,
 )
 from repro.query.ast import And, Eq, In, Or, Pred, Query, Range
-from repro.query.bitmap import BitmapStore, validate_batch
+from repro.query.bitmap import BitmapStore, distinct_values, validate_batch
 from repro.query.compile import QueryCompiler, compile_flush
 from repro.query.device import (
     FlashDevice,
@@ -274,7 +275,7 @@ class ShardedBitmapStore:
             raise ValueError("all ingests must share one row count")
         self.num_rows = n
         self.schema = {
-            col: tuple(int(v) for v in np.unique(np.asarray(vals)))
+            col: tuple(int(v) for v in distinct_values(np.asarray(vals)))
             for col, vals in table.items()
         }
         if self.stripe_key is not None:
@@ -308,7 +309,7 @@ class ShardedBitmapStore:
                 continue
             sub = {col: np.asarray(v)[rows] for col, v in table.items()}
             self.shard_values[s] = {
-                col: tuple(int(v) for v in np.unique(vals))
+                col: tuple(int(v) for v in distinct_values(vals))
                 for col, vals in sub.items()
             }
             if self.stripe_key is not None:
@@ -1504,7 +1505,10 @@ class ShardedFlashQL:
                 tele.gauge(f"shard{s}.routed_depth", d)
         inflight: deque = deque()
         for s in active:
-            entry = self._dispatch_shard(s, depths[s])
+            # the shard's index/mask/extra-plane uploads land on the
+            # accelerator its stripe lives on, so its program runs there
+            with jax.default_device(self.devices[s].store.device):
+                entry = self._dispatch_shard(s, depths[s])
             if entry is not None:
                 inflight.append(entry)
             # double buffer: collect shard k's payload only after shard
@@ -1532,6 +1536,15 @@ class ShardedFlashQL:
 
     # -- lockstep (cross-shard fused) flushing -------------------------------
     def _flush_lockstep(self) -> dict[int, QueryResult]:
+        placed = {d.store.device for d in self.devices}
+        if len(placed) > 1:
+            # the lockstep path stacks every stripe's snapshot into one
+            # array, which would copy the whole fleet onto one chip
+            raise RuntimeError(
+                "lockstep flushing needs every shard on one device; this "
+                f"fleet spans {len(placed)} devices — serve it with "
+                "pipeline=True (cross-device lockstep is ROADMAP B1)"
+            )
         active = [s for s in self.store.active if self._queues[s]]
         expected = len(self.store.active)
         if not active and not any(
@@ -1909,7 +1922,7 @@ def build_sharded_flashql(
     num_planes: int = 4,
     warmup: Iterable[Query] = (),
     queue_depth: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
     reserve_rows: int = 0,
     pipeline: bool = False,
     coalesce_appends: bool = False,
@@ -1933,13 +1946,22 @@ def build_sharded_flashql(
         reserve_rows=reserve_rows,
     )
     store.ingest(table)
+    # a pipelined fleet puts stripe s on accelerator s (round-robin past
+    # the device count); a lockstep fleet stacks its stripes into one
+    # array, so it stays on JAX's default device (ROADMAP B1), as does
+    # every fleet on a single-device host
+    chips = jax.devices() if pipeline else []
     devices = [
         FlashDevice(
             num_planes=num_planes,
             interpret=interpret,
             layout=Layout(levels=levels),
+            store=PackedStore(
+                planes=num_planes,
+                device=chips[s % len(chips)] if len(chips) > 1 else None,
+            ),
         )
-        for _ in range(num_shards)
+        for s in range(num_shards)
     ]
     store.program(devices, warmup=warmup)
     return ShardedFlashQL(

@@ -4,13 +4,17 @@ Sweeps shapes/dtypes and asserts bit-exact equality (interpret=True executes
 the kernel body on CPU; the BlockSpec tiling logic is exercised for real).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # hypothesis optional
 
 from repro.core.bitops import BitOp, pack_bits, reduce_words, unpack_bits
+from repro.core.engine import fused_block_reduce, threshold_block_reduce
 from repro.kernels.mws import mws_reduce, mws_reduce_ref, parabit_reduce
+from repro.kernels.mws.mws import mws_block_reduce_pallas
+from repro.kernels.popcount import popcount
 
 ALL_OPS = list(BitOp)
 
@@ -131,3 +135,45 @@ def test_reduce_words_matches_kernel_ref():
         np.testing.assert_array_equal(
             np.asarray(reduce_words(x, op)), np.asarray(mws_reduce_ref(x, op))
         )
+
+
+@pytest.mark.parametrize(
+    "k,n,w", [(1, 1, 1), (3, 5, 200), (2, 30, 4100), (4, 9, 2048), (1, 48, 300)]
+)
+def test_block_reduce_kernel_matches_fold(k, n, w):
+    """The one-dispatch MWS kernel (AND within each block, OR across
+    blocks, ragged wordline counts and word tiles) against the fold."""
+    rng = np.random.default_rng(k * 100 + n * 10 + w)
+    cube = _rand_stack(rng, k * n, w, jnp.uint32).reshape(k, n, w)
+    cube = cube | _rand_stack(rng, k * n, w, jnp.uint32).reshape(k, n, w)
+    anded = np.stack([np.asarray(reduce_words(cube[b], BitOp.AND)) for b in range(k)])
+    np.testing.assert_array_equal(
+        np.asarray(mws_block_reduce_pallas(cube, or_blocks=False, interpret=True)),
+        anded,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(mws_block_reduce_pallas(cube, or_blocks=True, interpret=True)),
+        np.asarray(reduce_words(jnp.asarray(anded), BitOp.OR)),
+    )
+
+
+def test_default_kernel_path_on_cpu_is_emulation():
+    """With no ``interpret`` given, a program lowered for the CPU carries
+    no TPU kernel and computes what the explicit emulation computes."""
+    rng = np.random.default_rng(12)
+    cube = _rand_stack(rng, 12, 300, jnp.uint32).reshape(3, 4, 300)
+    for fn in (
+        lambda c: fused_block_reduce(c, True),
+        lambda c: threshold_block_reduce(c, 2, False),
+        lambda c: popcount(c[0]),
+    ):
+        compiled = jax.jit(fn).lower(cube).compile()
+        assert "tpu_custom_call" not in compiled.as_text()
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda c: fused_block_reduce(c, True))(cube)),
+        np.asarray(fused_block_reduce(cube, True, interpret=True)),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda c: threshold_block_reduce(c, 2, False))(cube)),
+        np.asarray(threshold_block_reduce(cube, 2, False, interpret=True)),
+    )

@@ -468,3 +468,23 @@ def test_in_unknown_values_and_empty():
     assert r1.count == int((table["c"] == 0).sum())
     assert r2.count == 0
     assert r3.count == 100
+
+
+def test_served_default_path_resolves_to_emulation_on_cpu():
+    """No ``interpret`` anywhere: the device takes the kernel path from
+    the platform — emulation on the CPU — and its fused flush program
+    carries no TPU kernel while answering like the oracle."""
+    rng = np.random.default_rng(21)
+    t = _rand_table(rng, 700)
+    store = BitmapStore()
+    store.ingest(t)
+    dev = FlashDevice(num_planes=2)
+    assert dev.interpret is True
+    store.program(dev)
+    sched = BatchScheduler(dev, store)
+    preds = [qand(Eq("country", 2), Range("age", 10, 60)), In("device", [1, 3])]
+    for q, r in zip(preds, sched.serve([Query(p) for p in preds])):
+        assert r.count == int(_np_oracle(q, t, 700).sum())
+    mask = jnp.asarray(store.valid_words_mask())
+    hlo = sched.last_program.lower(dev.store.snapshot(), mask).compile().as_text()
+    assert "tpu_custom_call" not in hlo

@@ -22,6 +22,10 @@ pages and a plain-numpy oracle on the raw table.
 Property-style execution goes through ``tests/_hypothesis_compat``: with
 `hypothesis` installed, seeds/shapes are drawn adversarially; without it,
 the deterministic ``CORPUS`` below keeps the same coverage running.
+
+The append, CRUD, optimizer and threshold streams live in
+``tests/test_query_differential_*.py`` (shared pieces in
+``tests/_differential.py``), so a parallel run spreads them over workers.
 """
 
 import numpy as np
@@ -30,36 +34,28 @@ import pytest
 from repro.core.engine import eval_expr
 from repro.query import (
     Agg,
-    AtLeast,
-    Avg,
     BatchScheduler,
     BitmapStore,
     Count,
     Eq,
     FlashDevice,
-    GroupBy,
-    In,
-    Majority,
     Mask,
-    Max,
-    Min,
-    Not,
     Query,
-    Range,
-    Sum,
-    TopK,
     build_sharded_flashql,
     lower,
 )
 from repro.query.oracle import np_select as _np_oracle
-from repro.query.ast import and_ as qand, normalize_agg, or_ as qor
+from repro.query.ast import normalize_agg
 
 from tests._hypothesis_compat import given, settings, st
-
-SHARD_COUNTS = (1, 2, 3)
-# ragged on purpose: 97 is prime (never divides), 130 straddles a word
-# boundary (128 = 4 words), 31 is below one packed word
-ROW_COUNTS = (97, 130, 31)
+from tests._differential import (
+    SHARD_COUNTS,
+    ROW_COUNTS,
+    _table,
+    _random_pred,
+    _random_agg,
+    _np_agg_oracle,
+)
 
 # deterministic fallback corpus: (seed, num_rows, policy)
 CORPUS = [
@@ -70,88 +66,6 @@ CORPUS = [
     (15, 31, "roundrobin"),
     (16, 31, "range"),
 ]
-
-
-def _table(rng, n):
-    """Mixed-index table: low-cardinality equality columns + a BSI column."""
-    return {
-        "country": rng.integers(0, 6, n),
-        "device": rng.integers(0, 4, n),
-        "age": rng.integers(0, 90, n),
-    }
-
-
-def _random_pred(rng, depth=0):
-    kind = rng.integers(0, 6 if depth < 2 else 4)
-    if kind == 0:
-        return Eq("country", int(rng.integers(0, 7)))  # 6 may be absent
-    if kind == 1:
-        return In(
-            "device", [int(v) for v in rng.choice(5, rng.integers(1, 4))]
-        )
-    if kind == 2:
-        lo = int(rng.integers(0, 70))
-        return Range("age", lo, lo + int(rng.integers(0, 40)))
-    if kind == 3:
-        return Not(_random_pred(rng, depth + 1))
-    children = [
-        _random_pred(rng, depth + 1) for _ in range(rng.integers(2, 4))
-    ]
-    return qand(*children) if kind == 4 else qor(*children)
-
-
-def _random_agg(rng):
-    """Draw one of the non-trivial aggregates over a random column."""
-    col = ("country", "device", "age")[int(rng.integers(0, 3))]
-    kind = int(rng.integers(0, 6))
-    if kind == 0:
-        return Sum(col)
-    if kind == 1:
-        return Avg(col)
-    if kind == 2:
-        return Min(col)
-    if kind == 3:
-        return Max(col)
-    if kind == 4:
-        return TopK(col, int(rng.integers(1, 5)))
-    key = ("country", "device")[int(rng.integers(0, 2))]
-    inner = (Count(), Sum("age"), Avg("age"))[int(rng.integers(0, 3))]
-    return GroupBy(key, inner)
-
-
-def _np_agg_oracle(spec, sel, table):
-    """Plain-numpy aggregate over the selected-row mask ``sel``."""
-    if isinstance(spec, Sum):
-        return int(table[spec.column][sel].sum())
-    if isinstance(spec, Avg):
-        c = int(sel.sum())
-        return int(table[spec.column][sel].sum()) / c if c else None
-    if isinstance(spec, Min):
-        v = table[spec.column][sel]
-        return int(v.min()) if len(v) else None
-    if isinstance(spec, Max):
-        v = table[spec.column][sel]
-        return int(v.max()) if len(v) else None
-    if isinstance(spec, TopK):
-        vals, counts = np.unique(table[spec.column][sel], return_counts=True)
-        ranked = sorted(
-            zip(vals.tolist(), counts.tolist()),
-            key=lambda vc: (-vc[1], vc[0]),
-        )
-        return tuple((int(v), int(c)) for v, c in ranked)[: spec.k]
-    assert isinstance(spec, GroupBy)
-    out = {}
-    for v in np.unique(table[spec.key]):
-        m = sel & (table[spec.key] == v)
-        c = int(m.sum())
-        if not c:
-            continue
-        if isinstance(spec.value, Count):
-            out[int(v)] = c
-        else:
-            out[int(v)] = _np_agg_oracle(spec.value, m, table)
-    return out
-
 
 
 
@@ -249,282 +163,6 @@ def test_differential_corpus(seed, n, policy):
     _run_differential(seed, n, policy)
 
 
-# ---------------------------------------------------------------------------
-# incremental ingest: interleaved append/query streams vs rebuild oracle
-# ---------------------------------------------------------------------------
-
-# (seed, num_rows, policy) for the append stream; both policies and the
-# stripe_key-routed fleet are exercised for every entry
-APPEND_CORPUS = [
-    (21, 97, "roundrobin"),
-    (22, 97, "range"),
-    (23, 130, "range"),
-    (24, 31, "roundrobin"),
-]
-
-
-def _check_round(queries, results, table, n):
-    """Assert one system's results bit-exact vs the numpy oracle on the
-    rows resident so far (exact integers for SUM / the AVG numerator)."""
-    for q, r in zip(queries, results):
-        want_bits = _np_oracle(q.where, table, n)
-        spec = normalize_agg(q.agg)
-        if isinstance(spec, Count):
-            assert r.count == int(want_bits.sum()), (q, r.count)
-        elif isinstance(spec, Mask):
-            got = np.asarray(r.mask.to_bits()).astype(bool)
-            np.testing.assert_array_equal(got, want_bits, err_msg=f"{q}")
-        else:
-            want = _np_agg_oracle(spec, want_bits, table)
-            assert r.value == want, (q, r.value, want)
-
-
-def _run_append_differential(seed: int, n: int, policy: str) -> None:
-    """Interleaved append/query stream, checked bit-exactly after every
-    round against (a) a numpy oracle on the resident prefix and (b) a
-    BitmapStore REBUILT from scratch on the same prefix — across shard
-    counts {1, 2, 3}, both striping policies, and a stripe_key fleet."""
-    rng = np.random.default_rng(seed)
-    table = _table(rng, n)
-    n0 = max(8, (2 * n) // 3)
-    cut = n0 + max(1, (n - n0) // 2)
-    # force index-metadata growth mid-stream: a country value and an age
-    # bit width that FIRST appear in an append (GROUP BY must grow a
-    # group; Range lowering must pick up the new BSI slice)
-    table["country"][n0] = 11
-    table["age"][cut] = 300
-    prefixes = [n0, cut, n]
-
-    def prefix(m):
-        return {c: v[:m] for c, v in table.items()}
-
-    reserve = n - n0
-    store = BitmapStore()
-    store.ingest(prefix(n0), reserve_rows=reserve)
-    dev = FlashDevice(num_planes=2)
-    store.program(dev)
-    systems: dict[object, object] = {
-        "unsharded": BatchScheduler(dev, store),
-        **{
-            s: build_sharded_flashql(
-                prefix(n0), s, policy=policy, num_planes=2,
-                reserve_rows=reserve,
-            )
-            for s in SHARD_COUNTS
-        },
-    }
-    # async fused flushing and append coalescing ride the same stream
-    systems["pipelined"] = build_sharded_flashql(
-        prefix(n0), 2, policy=policy, num_planes=2,
-        reserve_rows=reserve, pipeline=True,
-    )
-    systems["coalesced"] = build_sharded_flashql(
-        prefix(n0), 2, policy=policy, num_planes=2,
-        reserve_rows=reserve, pipeline=True, coalesce_appends=True,
-    )
-    if policy == "range":
-        systems["routed"] = build_sharded_flashql(
-            prefix(n0), 3, policy="range", stripe_key="age",
-            num_planes=2, reserve_rows=reserve,
-        )
-
-    warm_queries = [_random_pred(rng) for _ in range(2)]
-    for round_i, m in enumerate(prefixes):
-        if round_i:
-            lo = prefixes[round_i - 1]
-            batch = {c: v[lo:m] for c, v in table.items()}
-            for sys in systems.values():
-                sys.append(batch)
-        preds = [_random_pred(rng) for _ in range(2)] + warm_queries
-        queries = (
-            [Query(p) for p in preds[:2]]
-            + [Query(p, agg=Agg.MASK) for p in preds[2:3]]
-            + [Query(p, agg=_random_agg(rng)) for p in preds]
-            + [Query(Eq("country", 11), agg=GroupBy("country", Count()))]
-        )
-        # rebuild-from-scratch oracle on the same resident prefix
-        rstore = BitmapStore()
-        rstore.ingest(prefix(m))
-        rdev = FlashDevice(num_planes=2)
-        rstore.program(rdev)
-        rebuilt = BatchScheduler(rdev, rstore).serve(queries)
-        _check_round(queries, rebuilt, prefix(m), m)
-        for name, sys in systems.items():
-            got = sys.serve(queries)
-            _check_round(queries, got, prefix(m), m)
-            for want, have in zip(rebuilt, got):
-                if isinstance(normalize_agg(want.query.agg), Mask):
-                    np.testing.assert_array_equal(
-                        np.asarray(want.mask.to_bits()),
-                        np.asarray(have.mask.to_bits()),
-                        err_msg=f"{(seed, n, policy, name)}",
-                    )
-                else:
-                    assert want.value == have.value, (
-                        seed, n, policy, name, want.query,
-                    )
-
-
-@pytest.mark.parametrize("seed,n,policy", APPEND_CORPUS)
-def test_append_differential_corpus(seed, n, policy):
-    """Deterministic append-stream corpus: always runs."""
-    _run_append_differential(seed, n, policy)
-
-
-@settings(max_examples=4, deadline=None)
-@given(
-    seed=st.integers(min_value=0, max_value=2**16),
-    n=st.sampled_from(ROW_COUNTS),
-    policy=st.sampled_from(["roundrobin", "range"]),
-)
-def test_append_differential_property(seed, n, policy):
-    """Property-style append streams: hypothesis drives seeds when
-    installed; the shim skips this (the corpus above still runs)."""
-    _run_append_differential(seed, n, policy)
-
-
-# ---------------------------------------------------------------------------
-# full CRUD: interleaved append/delete/update/query streams + compaction
-# ---------------------------------------------------------------------------
-
-LIFECYCLE_CORPUS = [
-    (31, 97, "roundrobin"),
-    (32, 97, "range"),
-    (33, 130, "range"),
-    (34, 31, "roundrobin"),
-]
-
-
-def _check_live_round(queries, results, resident, live):
-    """One system's results vs the numpy oracle restricted to LIVE rows:
-    tombstoned rows must never appear in any COUNT, MASK, or aggregate."""
-    n = len(live)
-    for q, r in zip(queries, results):
-        want_bits = _np_oracle(q.where, resident, n) & live
-        spec = normalize_agg(q.agg)
-        if isinstance(spec, Count):
-            assert r.count == int(want_bits.sum()), (q, r.count)
-        elif isinstance(spec, Mask):
-            got = np.asarray(r.mask.to_bits()).astype(bool)
-            np.testing.assert_array_equal(got, want_bits, err_msg=f"{q}")
-        else:
-            want = _np_agg_oracle(spec, want_bits, resident)
-            assert r.value == want, (q, r.value, want)
-
-
-def _run_lifecycle_differential(seed: int, n: int, policy: str) -> None:
-    """Random interleaved append/delete/update/query stream, bit-exact
-    after every round vs a live-row numpy oracle — across shard counts
-    {1, 2, 3}, both striping policies, a stripe_key fleet, and the
-    unsharded scheduler; compaction fires mid-stream and must preserve
-    results exactly while bumping epochs ONLY on rewritten stripes."""
-    rng = np.random.default_rng(seed)
-    resident = _table(rng, n)
-    live = np.ones(n, bool)
-    reserve = n  # headroom for the appended/updated rows
-
-    def build_unsharded():
-        store = BitmapStore()
-        store.ingest(dict(resident), reserve_rows=reserve)
-        dev = FlashDevice(num_planes=2)
-        store.program(dev)
-        return BatchScheduler(dev, store)
-
-    systems: dict[object, object] = {
-        "unsharded": build_unsharded(),
-        **{
-            s: build_sharded_flashql(
-                dict(resident), s, policy=policy, num_planes=2,
-                reserve_rows=reserve,
-            )
-            for s in SHARD_COUNTS
-        },
-    }
-    if policy == "range":
-        systems["routed"] = build_sharded_flashql(
-            dict(resident), 3, policy="range", stripe_key="age",
-            num_planes=2, reserve_rows=reserve,
-        )
-
-    def apply_all(op):
-        for sys in systems.values():
-            op(sys)
-
-    warm = [_random_pred(rng) for _ in range(2)]
-    for round_i in range(5):
-        # -- one random mutation per round, mirrored into the model
-        kind = ("append", "delete", "update", "compact", "delete")[round_i]
-        if kind == "append":
-            b = int(rng.integers(3, 10))
-            batch = _table(rng, b)
-            apply_all(lambda s: s.append(batch))
-            resident = {
-                c: np.concatenate([v, batch[c]]) for c, v in resident.items()
-            }
-            live = np.concatenate([live, np.ones(b, bool)])
-        elif kind == "delete":
-            pool = np.flatnonzero(live)
-            ids = rng.choice(pool, min(len(pool) // 3, 25), replace=False)
-            apply_all(lambda s: s.delete(ids))
-            live[ids] = False
-        elif kind == "update":
-            pool = np.flatnonzero(live)
-            ids = rng.choice(pool, min(len(pool), 6), replace=False)
-            rows = _table(rng, len(ids))
-            apply_all(lambda s: s.update(ids, rows))
-            live[ids] = False
-            resident = {
-                c: np.concatenate([v, rows[c]]) for c, v in resident.items()
-            }
-            live = np.concatenate([live, np.ones(len(ids), bool)])
-        else:  # compact — epochs may move ONLY on rewritten stripes
-            probe = systems[3]
-            pre = [d.store.epoch for d in probe.devices]
-            dirty = [sh.deleted_rows > 0 for sh in probe.store.shards]
-            apply_all(lambda s: s.compact())
-            post = [d.store.epoch for d in probe.devices]
-            for was_dirty, a, b in zip(dirty, pre, post):
-                assert (b > a) == was_dirty, (seed, n, policy, dirty)
-            resident = {c: v[live] for c, v in resident.items()}
-            live = np.ones(int(live.sum()), bool)
-            assert systems["unsharded"].store.num_rows == len(live)
-            assert systems[3].store.num_rows == len(live)
-
-        # -- every system answers every query identically to the oracle
-        preds = [_random_pred(rng) for _ in range(2)] + warm
-        queries = (
-            [Query(p) for p in preds[:2]]
-            + [Query(p, agg=Agg.MASK) for p in preds]
-            + [Query(preds[0], agg=_random_agg(rng))]
-        )
-        for name, sys in systems.items():
-            got = sys.serve(queries)
-            try:
-                _check_live_round(queries, got, resident, live)
-            except AssertionError as err:
-                raise AssertionError(
-                    f"{(seed, n, policy, name, round_i, kind)}: {err}"
-                ) from err
-
-
-@pytest.mark.parametrize("seed,n,policy", LIFECYCLE_CORPUS)
-def test_lifecycle_differential_corpus(seed, n, policy):
-    """Deterministic CRUD-stream corpus: always runs."""
-    _run_lifecycle_differential(seed, n, policy)
-
-
-@settings(max_examples=4, deadline=None)
-@given(
-    seed=st.integers(min_value=0, max_value=2**16),
-    n=st.sampled_from(ROW_COUNTS),
-    policy=st.sampled_from(["roundrobin", "range"]),
-)
-def test_lifecycle_differential_property(seed, n, policy):
-    """Property-style CRUD streams: hypothesis drives seeds when
-    installed; the shim skips this (the corpus above still runs)."""
-    _run_lifecycle_differential(seed, n, policy)
-
-
 @settings(max_examples=8, deadline=None)
 @given(
     seed=st.integers(min_value=0, max_value=2**16),
@@ -535,125 +173,6 @@ def test_differential_property(seed, n, policy):
     """Property-style: hypothesis drives seeds when installed; the shim
     skips this (the corpus above still runs) when it is not."""
     _run_differential(seed, n, policy)
-
-
-# ---------------------------------------------------------------------------
-# optimizer stream: Zipf-skewed repeated predicates + appends/deletes
-# ---------------------------------------------------------------------------
-
-OPTIMIZER_CORPUS = [
-    (41, 97, "roundrobin"),
-    (42, 130, "range"),
-    (43, 31, "range"),
-]
-
-
-def _hot_pool(rng):
-    """A small predicate pool whose hottest member is guaranteed
-    composite (multi-page after lowering), so a skewed stream crosses the
-    materialization threshold on every seed."""
-    return [qand(Range("age", 10, 60), In("device", [0, 1]))] + [
-        _random_pred(rng) for _ in range(5)
-    ]
-
-
-def _run_optimizer_differential(seed: int, n: int, policy: str) -> None:
-    """Zipf-skewed repeated-predicate stream under a LOW materialization
-    threshold: hot predicates materialize mid-stream, and the interleaved
-    appends/deletes drive their cached pages through the epoch guards —
-    appends must invalidate (the cached bitmap would zero-miss new rows),
-    deletes must not (the valid page composes at read time).  Every round
-    is bit-exact vs the live-row numpy oracle on the unsharded scheduler,
-    the lockstep fleet, and the pipelined fleet, all with CSE active."""
-    rng = np.random.default_rng(seed)
-    resident = _table(rng, n)
-    live = np.ones(n, bool)
-    reserve = n
-
-    def build_unsharded():
-        store = BitmapStore()
-        store.ingest(dict(resident), reserve_rows=reserve)
-        dev = FlashDevice(num_planes=2)
-        store.program(dev)
-        return BatchScheduler(dev, store, materialize_after=2)
-
-    systems: dict[object, object] = {
-        "unsharded": build_unsharded(),
-        "lockstep": build_sharded_flashql(
-            dict(resident), 3, policy=policy, num_planes=2,
-            reserve_rows=reserve, materialize_after=2,
-        ),
-        "pipelined": build_sharded_flashql(
-            dict(resident), 2, policy=policy, num_planes=2,
-            reserve_rows=reserve, pipeline=True, materialize_after=2,
-        ),
-    }
-
-    pool = _hot_pool(rng)
-    for round_i in range(4):
-        kind = (None, "append", "delete", "append")[round_i]
-        if kind == "append":
-            b = int(rng.integers(3, 8))
-            batch = _table(rng, b)
-            for sys in systems.values():
-                sys.append(batch)
-            resident = {
-                c: np.concatenate([v, batch[c]]) for c, v in resident.items()
-            }
-            live = np.concatenate([live, np.ones(b, bool)])
-        elif kind == "delete":
-            rows = np.flatnonzero(live)
-            ids = rng.choice(rows, min(len(rows) // 4, 15), replace=False)
-            for sys in systems.values():
-                sys.delete(ids)
-            live[ids] = False
-        # Zipf-skewed draw over the pool: rank 1 (by far the most likely)
-        # maps to the composite hot predicate, so duplicates recur within
-        # AND across flushes — exercising dedup, CSE, and materialization
-        ranks = (rng.zipf(1.5, size=10).astype(int) - 1) % len(pool)
-        preds = [pool[r] for r in ranks]
-        queries = [Query(p) for p in preds] + [
-            Query(pool[0], agg=Agg.MASK)
-        ]
-        for name, sys in systems.items():
-            got = sys.serve(queries)
-            try:
-                _check_live_round(queries, got, resident, live)
-            except AssertionError as err:
-                raise AssertionError(
-                    f"{(seed, n, policy, name, round_i, kind)}: {err}"
-                ) from err
-
-    # the stream is hot enough that every system materialized the hot
-    # predicate, and both appends invalidated its cached page (the delete
-    # round must NOT have: tombstones compose at read time)
-    for name, sys in systems.items():
-        comps = (
-            [sys.compiler] if name == "unsharded" else list(sys.compilers)
-        )
-        builds = sum(c.mat_builds for c in comps)
-        invals = sum(c.mat_invalidations for c in comps)
-        assert builds >= 1, (seed, n, policy, name, builds)
-        assert invals >= 1, (seed, n, policy, name, invals)
-        assert sys.stats()["materializations"] == builds
-
-
-@pytest.mark.parametrize("seed,n,policy", OPTIMIZER_CORPUS)
-def test_optimizer_differential_corpus(seed, n, policy):
-    """Deterministic skewed-stream corpus: always runs."""
-    _run_optimizer_differential(seed, n, policy)
-
-
-@settings(max_examples=3, deadline=None)
-@given(
-    seed=st.integers(min_value=0, max_value=2**16),
-    n=st.sampled_from(ROW_COUNTS),
-    policy=st.sampled_from(["roundrobin", "range"]),
-)
-def test_optimizer_differential_property(seed, n, policy):
-    """Property-style skewed streams: hypothesis drives seeds when
-    installed; the shim skips this (the corpus above still runs)."""
-    _run_optimizer_differential(seed, n, policy)
 
 
 def test_sharded_handles_rows_fewer_than_shards():
@@ -681,124 +200,3 @@ def test_roundrobin_mask_unstripes_row_order():
     np.testing.assert_array_equal(
         np.asarray(r.mask.to_bits()).astype(bool), (np.arange(n) % 3) == 0
     )
-
-
-# ---------------------------------------------------------------------------
-# threshold stream: random k-of-N AtLeast predicates + appends/deletes
-# ---------------------------------------------------------------------------
-
-THRESHOLD_CORPUS = [
-    (61, 97, "roundrobin"),
-    (62, 130, "range"),
-    (63, 31, "roundrobin"),
-]
-
-
-def _random_atleast(rng, depth=0):
-    """Random k-of-N threshold predicate over mixed leaf/compound children.
-
-    Deliberately includes the degenerate k values (1 => Or, N => And) so
-    the canonicalization path is exercised alongside genuine thresholds,
-    and nests thresholds under Not/And/Or (and inside each other one level
-    deep) so every planner lowering — native ThresholdCommand, polarity
-    inversion, chain expansion — gets hit by the stream.
-    """
-    n_kids = int(rng.integers(2, 6))
-    kids = []
-    for _ in range(n_kids):
-        if depth < 1 and rng.integers(0, 4) == 0:
-            kids.append(_random_atleast(rng, depth + 1))
-        else:
-            kids.append(_random_pred(rng, depth=2))
-    k = int(rng.integers(1, len(kids) + 1))
-    pred = AtLeast(k, kids)
-    wrap = rng.integers(0, 4)
-    if wrap == 0:
-        return Not(pred)
-    if wrap == 1:
-        return qand(pred, _random_pred(rng, depth=2))
-    if wrap == 2:
-        return qor(pred, _random_pred(rng, depth=2))
-    return pred
-
-
-def _run_threshold_differential(seed: int, n: int, policy: str) -> None:
-    """Interleaved append/delete/query stream of AtLeast predicates,
-    bit-exact after every round vs the live-row numpy oracle — across the
-    unsharded scheduler and shard counts {1, 2, 3}."""
-    rng = np.random.default_rng(seed)
-    resident = _table(rng, n)
-    live = np.ones(n, bool)
-    reserve = n
-
-    def build_unsharded():
-        store = BitmapStore()
-        store.ingest(dict(resident), reserve_rows=reserve)
-        dev = FlashDevice(num_planes=2)
-        store.program(dev)
-        return BatchScheduler(dev, store)
-
-    systems: dict[object, object] = {
-        "unsharded": build_unsharded(),
-        **{
-            s: build_sharded_flashql(
-                dict(resident), s, policy=policy, num_planes=2,
-                reserve_rows=reserve,
-            )
-            for s in SHARD_COUNTS
-        },
-    }
-
-    warm = [_random_atleast(rng) for _ in range(2)]
-    for round_i in range(4):
-        kind = ("append", "delete", "append", "delete")[round_i]
-        if kind == "append":
-            b = int(rng.integers(3, 10))
-            batch = _table(rng, b)
-            for sys in systems.values():
-                sys.append(batch)
-            resident = {
-                c: np.concatenate([v, batch[c]]) for c, v in resident.items()
-            }
-            live = np.concatenate([live, np.ones(b, bool)])
-        else:
-            pool = np.flatnonzero(live)
-            ids = rng.choice(pool, min(len(pool) // 4, 20), replace=False)
-            for sys in systems.values():
-                sys.delete(ids)
-            live[ids] = False
-
-        preds = [_random_atleast(rng) for _ in range(3)] + warm
-        queries = (
-            [Query(p) for p in preds[:3]]
-            + [Query(p, agg=Agg.MASK) for p in preds[3:]]
-            + [Query(Majority([
-                Eq("country", 1), Eq("device", 2), Range("age", 20, 60),
-            ]))]
-        )
-        for name, sys in systems.items():
-            got = sys.serve(queries)
-            try:
-                _check_live_round(queries, got, resident, live)
-            except AssertionError as err:
-                raise AssertionError(
-                    f"{(seed, n, policy, name, round_i, kind)}: {err}"
-                ) from err
-
-
-@pytest.mark.parametrize("seed,n,policy", THRESHOLD_CORPUS)
-def test_threshold_differential_corpus(seed, n, policy):
-    """Deterministic k-of-N threshold stream corpus: always runs."""
-    _run_threshold_differential(seed, n, policy)
-
-
-@settings(max_examples=4, deadline=None)
-@given(
-    seed=st.integers(min_value=0, max_value=2**16),
-    n=st.sampled_from(ROW_COUNTS),
-    policy=st.sampled_from(["roundrobin", "range"]),
-)
-def test_threshold_differential_property(seed, n, policy):
-    """Property-style threshold streams: hypothesis drives seeds when
-    installed; the shim skips this (the corpus above still runs)."""
-    _run_threshold_differential(seed, n, policy)

@@ -2,6 +2,12 @@
 plan-aware batching, scheduler stat accounting under multi-shard
 admission, and the fleet projection."""
 
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -282,3 +288,79 @@ def test_fleet_projection_aggregates_devices():
         sum(p["fc_energy_j"] for p in proj["per_shard"])
     )
     assert proj["speedup_vs_osp"] > 0
+
+
+REPO = Path(__file__).resolve().parents[1]
+
+
+def _on_four_cpu_devices(body: str) -> str:
+    """Run ``body`` in a fresh interpreter that sees four virtual CPU
+    devices and has ``chip_smoke`` loaded as ``smoke`` (a subprocess,
+    because the device count is fixed when JAX starts); return stdout."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(REPO / "src")
+    script = (
+        textwrap.dedent(
+            f"""
+            import importlib.util, jax
+            import numpy as np
+            spec = importlib.util.spec_from_file_location(
+                "chip_smoke", {str(REPO / "chip_smoke.py")!r}
+            )
+            smoke = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(smoke)
+            assert len(jax.devices()) == 4
+            """
+        )
+        + textwrap.dedent(body)
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", script],
+        capture_output=True,
+        text=True,
+        env=env,
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return out.stdout
+
+
+def test_fleet_places_one_stripe_per_device_and_lockstep_refuses():
+    """Rehearsal of ``chip_smoke.py --chips 4`` on four virtual CPU devices:
+    stripe s lives on device s, the pipelined fleet answers like the numpy
+    oracle, and the lockstep path refuses to stack stripes of four devices
+    onto one."""
+    stdout = _on_four_cpu_devices(
+        """
+        smoke.FLEET_ROWS = 1 << 13
+        smoke.four_chips(jax)
+        """
+    )
+    for s in range(4):
+        assert f"on device {s}" in stdout
+    assert "lockstep across 4 devices refused" in stdout
+
+
+def test_default_fleet_on_four_devices_stays_on_one_and_serves():
+    """A fleet built without ``pipeline`` flushes in lockstep, which stacks
+    its stripes into one array: on a four-device host it keeps every stripe
+    on the default device and serves like the numpy oracle."""
+    stdout = _on_four_cpu_devices(
+        """
+        from repro.query import build_sharded_flashql
+        n = 1 << 13
+        table = smoke.make_table(np.random.default_rng(1), n)
+        fleet = build_sharded_flashql(table, 4, num_planes=4)
+        placed = set()
+        for dev in fleet.devices:
+            placed |= dev.store.snapshot().devices()
+        assert placed == {jax.devices()[0]}, placed
+        queries = smoke.smoke_queries()
+        wants = [smoke.oracle(q, [table], np.ones((n,), bool)) for q in queries]
+        smoke.serve_checked(fleet, queries, wants, "lockstep fleet")
+        print("default fleet served on one device")
+        """
+    )
+    assert "default fleet served on one device" in stdout
