@@ -1,0 +1,11 @@
+"""95th percentile (nearest rank) of the latencies of all requests due in
+the window, from each request's due time to its last answer."""
+
+from bench.stats import percentile
+
+UNIT = "ms"
+
+
+def read(r):
+    p = percentile(r.latencies_s, 95)
+    return None if p is None else p * 1e3
