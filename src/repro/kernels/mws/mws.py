@@ -114,6 +114,7 @@ def mws_reduce_pallas(
         out_specs=pl.BlockSpec((1, block_words), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, w), stack.dtype),
         interpret=interpret,
+        name="flashql_mws_reduce",
     )(stack)
     return out[0]
 
@@ -189,5 +190,6 @@ def mws_block_reduce_pallas(
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="flashql_mws_block_reduce",
     )(cube)
     return out[0] if or_blocks else out[:, 0]

@@ -102,5 +102,6 @@ def mws_count_pallas(
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, block_words), jnp.uint32)],
         interpret=interpret,
+        name="flashql_mws_count",
     )(stack)
     return out[0, 0]

@@ -69,5 +69,6 @@ def popcount_pallas(
         out_specs=pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, 1), jnp.int32),
         interpret=interpret,
+        name="flashql_popcount",
     )(words)
     return out[:, 0]

@@ -80,5 +80,6 @@ def threshold_pallas(
         out_specs=pl.BlockSpec((1, block_words), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, w), jnp.uint32),
         interpret=interpret,
+        name="flashql_threshold",
     )(anded)
     return out[0]

@@ -719,20 +719,22 @@ def group_extras(
 ):
     """Stacked ``(B_g, P, W)`` extra sensed planes of one reduce group.
 
-    Returns ``(extras, counts)`` where ``extras`` is the device stack (or
-    None when the group's aggregate senses no extra planes) and ``counts``
-    maps member index -> planes sensed (the caller's projected-traffic
-    accounting).  The group stack is memoized per member composition:
-    recurring flush compositions — steady-state serving — skip the
-    per-member fetches AND the device concat.
+    Returns ``(extras, counts, uploaded)`` where ``extras`` is the device
+    stack (or None when the group's aggregate senses no extra planes),
+    ``counts`` maps member index -> planes sensed (the caller's
+    projected-traffic accounting) and ``uploaded`` is the bytes of host
+    pages this call copied to the device.  The group stack is memoized per
+    member composition: recurring flush compositions — steady-state
+    serving — skip the per-member fetches AND the device concat.
     """
     member_pages = [
         _cached_pages(aggs[i], stores[i], store_keys[i], extras_cache, cache_cap)
         for i in members
     ]
     counts: dict[int, int] = {}
+    uploaded = 0
     if not member_pages[0]:
-        return None, counts
+        return None, counts, uploaded
     cks = []
     for i, pages in zip(members, member_pages):
         counts[i] = len(pages)
@@ -746,12 +748,13 @@ def group_extras(
             if stack is None:
                 _evict_one(extras_cache, cache_cap)
                 stack = fetch_pages(stores[i], ck[1])
+                uploaded += stack.nbytes
                 extras_cache[ck] = stack
             stacks.append(stack)
         extras = jnp.stack(stacks)  # (B_g, P, W)
         _evict_one(extras_cache, cache_cap)
         extras_cache[gk] = extras
-    return extras, counts
+    return extras, counts, uploaded
 
 
 def reduce_flush(
@@ -802,7 +805,7 @@ def reduce_flush(
             if len(members) == n
             else masked[jnp.asarray(np.asarray(members, np.int32))]
         )
-        extras, counts = group_extras(
+        extras, counts, _ = group_extras(
             aggs, members, stores, store_keys, extras_cache, cache_cap
         )
         for i, c in counts.items():

@@ -610,6 +610,9 @@ class FlushProgram:
     reduce_parse: tuple  # per reduce group: (member tuple, payload leaves)
     extra_counts: tuple  # per member: extra planes sensed (traffic accounting)
     cse_idxs: tuple = ()  # per shared plan: tuple of (blocks, wls) arrays
+    # host->device bytes the build copied: index arrays, member gathers and
+    # extra-plane stacks fetched from host pages
+    upload_bytes: int = 0
 
     def run(self, data: jax.Array, mask: jax.Array) -> jax.Array:
         """Dispatch the fused program (async); returns the device payload."""
@@ -719,6 +722,7 @@ def compile_flush(
     inv = row_of[np.asarray(urep, dtype=np.int32)]
 
     aggs, rgroups = group_members(specs, stores)
+    uploaded = 0
     reduce_sigs: list[tuple] = []
     sels: list = []
     extras: list = []
@@ -726,9 +730,10 @@ def compile_flush(
     extra_counts = [0] * n
     for gkey, members in rgroups.items():
         kind, sig = gkey[0], gkey[1:]
-        ex, counts = group_extras(
+        ex, counts, fetched = group_extras(
             aggs, members, stores, store_keys, extras_cache, cache_cap
         )
+        uploaded += fetched
         for i, c in counts.items():
             extra_counts[i] = c
         reduce_sigs.append(
@@ -754,6 +759,14 @@ def compile_flush(
             runner_cache.clear()
         runner = make_flush_runner(key, interpret)
         runner_cache[rkey] = runner
+    inv_perm = jnp.asarray(inv)
+    cse_idxs = tuple(
+        tuple(jnp.asarray(x) for x in e.idxs) for e in shared_execs
+    )
+    uploaded += sum(
+        a.nbytes
+        for a in jax.tree_util.tree_leaves((group_idxs, inv_perm, sels, cse_idxs))
+    )
     return FlushProgram(
         key=key,
         runner=runner,
@@ -761,12 +774,11 @@ def compile_flush(
         n_sense_groups=len(sense),
         n_reduce_groups=len(reduce_sigs),
         group_idxs=tuple(group_idxs),
-        inv_perm=jnp.asarray(inv),
+        inv_perm=inv_perm,
         sels=tuple(sels),
         extras=tuple(extras),
         reduce_parse=tuple(parse),
         extra_counts=tuple(extra_counts),
-        cse_idxs=tuple(
-            tuple(jnp.asarray(x) for x in e.idxs) for e in shared_execs
-        ),
+        cse_idxs=cse_idxs,
+        upload_bytes=int(uploaded),
     )

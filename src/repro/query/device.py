@@ -329,35 +329,39 @@ def make_flush_runner(key: tuple, interpret: bool | None):
 
     sense, reduce_sigs, w, cse = key
 
+    # the module is named after this function (``jit_run``): the
+    # benchmark's device-time metrics find the flush program by that name
     def run(data, group_idxs, inv_perm, mask, sels, extras, cse_idxs):
-        shared = None
-        if cse:
-            # shared subexpressions first: K is small, so these run as
-            # plain (unvmapped) plans; members gather their rows below
-            vals = [
-                plan_step_fn(psig, interpret)(data, None, *idxs)
-                for psig, idxs in zip(cse, cse_idxs)
-            ]
-            shared = jnp.stack(vals)
-        pieces = []
-        for (psig, _n), idxs in zip(sense, group_idxs):
-            one = plan_step_fn(psig, interpret)
-            n_mws = len(idxs)
-            out = jax.vmap(one, in_axes=(None, None) + (0,) * n_mws)(
-                data, shared, *idxs
-            )
-            pieces.append(out[:, :w])
-        allout = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
-        masked = allout[inv_perm] & mask  # member order, padding zeroed
-        parts = []
-        for (kind, sig, _n, _p), sel, ex in zip(reduce_sigs, sels, extras):
-            sub = masked if sel is None else masked[sel]
-            out = kind_reduce(kind, sub, ex, sig, interpret=interpret)
-            parts.extend(
-                jnp.ravel(leaf).astype(jnp.uint32)
-                for leaf in jax.tree_util.tree_leaves(out)
-            )
-        return jnp.concatenate(parts)
+        with jax.named_scope("sense"):
+            shared = None
+            if cse:
+                # shared subexpressions first: K is small, so these run as
+                # plain (unvmapped) plans; members gather their rows below
+                vals = [
+                    plan_step_fn(psig, interpret)(data, None, *idxs)
+                    for psig, idxs in zip(cse, cse_idxs)
+                ]
+                shared = jnp.stack(vals)
+            pieces = []
+            for (psig, _n), idxs in zip(sense, group_idxs):
+                one = plan_step_fn(psig, interpret)
+                n_mws = len(idxs)
+                out = jax.vmap(one, in_axes=(None, None) + (0,) * n_mws)(
+                    data, shared, *idxs
+                )
+                pieces.append(out[:, :w])
+            allout = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+            masked = allout[inv_perm] & mask  # member order, padding zeroed
+        with jax.named_scope("reduce"):
+            parts = []
+            for (kind, sig, _n, _p), sel, ex in zip(reduce_sigs, sels, extras):
+                sub = masked if sel is None else masked[sel]
+                out = kind_reduce(kind, sub, ex, sig, interpret=interpret)
+                parts.extend(
+                    jnp.ravel(leaf).astype(jnp.uint32)
+                    for leaf in jax.tree_util.tree_leaves(out)
+                )
+            return jnp.concatenate(parts)
 
     return jax.jit(run)
 

@@ -161,19 +161,25 @@ def attribute_result(
     attr: dict | None,
     t_submit: float,
     t_end: float,
+    flush: int | None = None,
 ) -> None:
     """Shared per-result telemetry: ticket trace span, latency histogram,
-    and the slow-query log.  Called only when telemetry is enabled."""
+    and the slow-query log.  Called only when telemetry is enabled.
+    ``flush`` is the id of the flush that served the ticket, the id its
+    stage spans carry."""
     latency = t_end - t_submit
     tele.observe("query_latency_s", latency)
     sensings = attr["sensings"] if attr else 0
+    args = {"ticket": ticket, "sensings": sensings}
+    if flush is not None:
+        args["flush"] = flush
     tele.span(
         "ticket",
         "query",
         t_submit,
         t_end,
         tid=TID_TICKETS,
-        args={"ticket": ticket, "sensings": sensings},
+        args=args,
     )
     tele.slow(
         {
@@ -686,7 +692,14 @@ class BatchScheduler:
 
     # -- serving -------------------------------------------------------------
     def flush(self) -> dict[int, QueryResult]:
-        """Compile, batch-execute, and aggregate all pending queries."""
+        """Compile, batch-execute, and aggregate all pending queries.
+
+        The flush runs as four back-to-back stages (``compile``,
+        ``dispatch``, ``transfer``, ``reduce``; the legacy path records
+        ``reduce+transfer`` in place of ``transfer``), with ``build`` and
+        ``launch`` inside ``dispatch`` and ``wait`` and ``copy`` inside
+        ``transfer`` on the fused path; every stage span carries the flush
+        id (see :meth:`Telemetry.stage`)."""
         self.apply_appends()
         if not self._pending:
             return {}
@@ -696,109 +709,85 @@ class BatchScheduler:
             self._pending[: self.max_batch],
             self._pending[self.max_batch :],
         )
-        tele.gauge("pending_after_pop", len(self._pending))
-        t0 = time.perf_counter()
-        compiled = [self.compiler.compile(q) for _, q, _ in batch]
-        execs = [self.compiler.exec_for(cq) for cq in compiled]
-        t_comp = time.perf_counter()
-        if self._mask_cache is None or self._mask_cache[0] != self.store.epoch:
-            self._mask_cache = (
-                self.store.epoch,
-                jnp.asarray(self.store.valid_words_mask()),
-            )
-        mask_words = self._mask_cache[1]
-        queries = [q for _, q, _ in batch]
-        aggs = [get_aggregator(q.agg) for q in queries]
-
+        fid = int(self.flushes) + 1
+        with tele.stage("compile", flush=fid) as st:
+            compiled = [self.compiler.compile(q) for _, q, _ in batch]
+            execs = [self.compiler.exec_for(cq) for cq in compiled]
+        t0, t_comp = st.start, st.end
         cse = None
-        if self.fuse_flush and not self.device._non_esp:
-            # the fused path: ONE jitted program senses every signature
-            # group and reduces every aggregate kind device-side; ONE
-            # payload transfer brings back the whole flush.  Epochs inside
-            # the plan keys + the content epochs make stale hits impossible.
-            # Plan keys cover only the predicate side, so the members'
-            # aggregate specs join the key explicitly — the same predicates
-            # under different aggregates are different programs.
-            if self.optimize:
-                ckey = (
-                    tuple(cq.key for cq in compiled),
-                    self.store.epoch,
-                    self.device.store.epoch,
-                )
-                cse = self._cse_cache.get(ckey)
+        fused = self.fuse_flush and not self.device._non_esp
+        with tele.stage("dispatch", t_comp, flush=fid) as st:
+            if (
+                self._mask_cache is None
+                or self._mask_cache[0] != self.store.epoch
+            ):
+                valid = self.store.valid_words_mask()
+                self._mask_cache = (self.store.epoch, jnp.asarray(valid))
+                tele.count("h2d_bytes", valid.nbytes)
+            mask_words = self._mask_cache[1]
+            queries = [q for _, q, _ in batch]
+            aggs = [get_aggregator(q.agg) for q in queries]
+            if fused:
+                # the fused path: ONE jitted program senses every signature
+                # group and reduces every aggregate kind device-side; ONE
+                # payload transfer brings back the whole flush.
+                with tele.stage("build", flush=fid):
+                    cse, program = self._flush_program(
+                        compiled, execs, queries, aggs
+                    )
+                self.last_program = program
+                store = self.device.store
+                uploads = store.snapshot_uploads
+                data = store.snapshot()
+                if store.snapshot_uploads != uploads:
+                    tele.count("h2d_bytes", data.nbytes)
+                with tele.stage("launch", flush=fid):
+                    payload = program.run(data, mask_words)
                 if cse is None:
-                    if len(self._cse_cache) >= 64:
-                        self._cse_cache.clear()
-                    cse = cse_flush(compiled, self.compiler, self.device)
-                    self._cse_cache[ckey] = cse
-            key = (
-                tuple(cq.key for cq in compiled),
-                tuple(a.spec for a in aggs),
-                self.store.epoch,
-                self.device.store.epoch,
-            )
-            program = self._flush_programs.get(key)
-            if program is None:
-                if len(self._flush_programs) >= 64:
-                    self._flush_programs.clear()
-                program = compile_flush(
-                    execs if cse is None else list(cse.member_execs),
-                    [q.agg for q in queries],
-                    [self.store] * len(queries),
-                    [self.store.epoch] * len(queries),
-                    words=self.store.words,
-                    interpret=self.device.interpret,
-                    runner_cache=self._runner_cache,
-                    extras_cache=self._extras_cache,
-                    pad=self.device.pad_signatures,
-                    dedup_keys=(
-                        None if cse is None else list(cse.dedup_keys)
-                    ),
-                    shared_execs=() if cse is None else cse.shared_execs,
-                )
-                self._flush_programs[key] = program
-            self.last_program = program
-            payload = program.run(self.device.store.snapshot(), mask_words)
-            if cse is None:
-                age_spill_blocks(self.device.pec, execs)
+                    age_spill_blocks(self.device.pec, execs)
+                else:
+                    # wear: one run of each UNIQUE member plan + each
+                    # shared plan, plus one scratch program per shared
+                    # result
+                    age_spill_blocks(
+                        self.device.pec,
+                        [cse.member_execs[i] for i in cse.uix]
+                        + list(cse.shared_execs),
+                    )
+                    for b in cse.shared_blocks:
+                        self.device.pec[b] = self.device.pec.get(b, 0) + 1
+                tele.count("fused_dispatches")
+                self.device.last_signature_groups = program.n_sense_groups
             else:
-                # wear: one run of each UNIQUE member plan + each shared
-                # plan, plus one scratch program per shared result
-                age_spill_blocks(
-                    self.device.pec,
-                    [cse.member_execs[i] for i in cse.uix]
-                    + list(cse.shared_execs),
-                )
-                for b in cse.shared_blocks:
-                    self.device.pec[b] = self.device.pec.get(b, 0) + 1
-            tele.count("fused_dispatches")
-            self.device.last_signature_groups = program.n_sense_groups
-            t_disp = time.perf_counter()
+                # legacy path (devices with non-ESP pages, and the oracle
+                # for the differential harness): vmap batches + one reduce
+                # dispatch and one transfer per reduce signature
+                plans = [c.plan for c in compiled]
+                stacked = (
+                    self.device.execute_batch_stacked(
+                        plans,
+                        execs=execs,
+                        # epochs inside cq.key make the memoized grouping
+                        # impossible to hit stale
+                        batch_key=tuple(cq.key for cq in compiled),
+                    )
+                    & mask_words
+                )  # (B, W), padding zeroed
+        t_disp = st.end
+        if fused:
             # the single device->host copy of the flush (also the barrier
             # that keeps qps/latency from measuring only Python dispatch)
-            host = jax.device_get(payload)
-            tele.count("host_transfers")
-            t_xfer = time.perf_counter()
-            partials = program.unpack(host, aggs)
-            extra_counts = list(program.extra_counts)
-            tele.span("dispatch", "flush", t_comp, t_disp)
-            tele.span("transfer", "flush", t_disp, t_xfer)
+            with tele.stage("transfer", t_disp, flush=fid) as st:
+                with tele.stage("wait", flush=fid):
+                    payload.block_until_ready()
+                with tele.stage("copy", flush=fid):
+                    host = jax.device_get(payload)
+                tele.count("host_transfers")
+            t_xfer = st.end
+            with tele.stage("reduce", t_xfer, flush=fid) as st:
+                partials = program.unpack(host, aggs)
+                extra_counts = list(program.extra_counts)
         else:
-            # legacy path (devices with non-ESP pages, and the oracle for
-            # the differential harness): vmap batches + one reduce dispatch
-            # and one transfer per reduce signature
-            plans = [c.plan for c in compiled]
-            stacked = (
-                self.device.execute_batch_stacked(
-                    plans,
-                    execs=execs,
-                    # epochs inside cq.key make the memoized grouping
-                    # impossible to hit stale
-                    batch_key=tuple(cq.key for cq in compiled),
-                )
-                & mask_words
-            )  # (B, W), padding zeroed
-            t_disp = time.perf_counter()
             partials, extra_counts, n_groups = reduce_flush(
                 stacked,
                 [q.agg for q in queries],
@@ -812,10 +801,10 @@ class BatchScheduler:
             t_xfer = time.perf_counter()
             # force device work before timestamping, or qps/latency would
             # only measure the Python-side dispatch
-            jax.block_until_ready(stacked)
-            tele.span("dispatch", "flush", t_comp, t_disp)
+            with tele.stage("reduce", t_xfer, flush=fid) as st:
+                jax.block_until_ready(stacked)
             tele.span("reduce+transfer", "flush", t_disp, t_xfer)
-        t1 = time.perf_counter()
+        t1 = st.end
         if cse is not None:
             # physical traffic after CSE: each UNIQUE member plan runs once
             # (duplicates ride the member gather) plus each shared subplan
@@ -831,8 +820,10 @@ class BatchScheduler:
             tele.count("cse_rewritten_members", cse.n_rewritten)
             tele.count("cse_spill_programs", len(cse.shared_plans))
         results: dict[int, QueryResult] = {}
+        queue_wait = 0.0
         for i, ((ticket, q, t_submit), cq) in enumerate(zip(batch, compiled)):
             agg = aggs[i]
+            queue_wait += t0 - t_submit
             self._host_postprocess |= agg.host_postprocess
             if cse is None:
                 self.telemetry.count(
@@ -861,7 +852,8 @@ class BatchScheduler:
                     "transfer_s": t_xfer - t_disp,
                     "reduce_s": t1 - t_xfer,
                 }
-                attribute_result(tele, ticket, q, attr, t_submit, t1)
+                tele.observe("queue_wait_s", t0 - t_submit)
+                attribute_result(tele, ticket, q, attr, t_submit, t1, fid)
             results[ticket] = QueryResult(
                 ticket,
                 q,
@@ -873,20 +865,69 @@ class BatchScheduler:
             tele.count("total_latency_s", t1 - t_submit)
 
         tele.count("queries_served", len(batch))
+        tele.count("queue_wait_s", queue_wait)
         tele.count("flushes")
         tele.count("vmap_batches", self.device.last_signature_groups)
         tele.count("serve_time_s", t1 - t0)
-        tele.span("compile", "flush", t0, t_comp)
-        tele.span("reduce", "flush", t_xfer, t1)
         tele.span(
             "flush",
             "flush",
             t0,
             t1,
-            args={"flush": int(self.flushes), "batch": len(batch)},
+            args={"flush": fid, "batch": len(batch)},
         )
         tele.observe("flush_latency_s", t1 - t0)
         return results
+
+    def _flush_program(self, compiled, execs, queries, aggs):
+        """The flush's CSE rewrite and fused program, each looked up or
+        built; returns ``(cse, program)`` (``cse`` is None with the
+        optimizer off).
+
+        Epochs inside the plan keys + the content epochs make stale hits
+        impossible.  Plan keys cover only the predicate side, so the
+        members' aggregate specs join the key explicitly — the same
+        predicates under different aggregates are different programs.
+        """
+        cse = None
+        if self.optimize:
+            ckey = (
+                tuple(cq.key for cq in compiled),
+                self.store.epoch,
+                self.device.store.epoch,
+            )
+            cse = self._cse_cache.get(ckey)
+            if cse is None:
+                if len(self._cse_cache) >= 64:
+                    self._cse_cache.clear()
+                cse = cse_flush(compiled, self.compiler, self.device)
+                self._cse_cache[ckey] = cse
+        key = (
+            tuple(cq.key for cq in compiled),
+            tuple(a.spec for a in aggs),
+            self.store.epoch,
+            self.device.store.epoch,
+        )
+        program = self._flush_programs.get(key)
+        if program is None:
+            if len(self._flush_programs) >= 64:
+                self._flush_programs.clear()
+            program = compile_flush(
+                execs if cse is None else list(cse.member_execs),
+                [q.agg for q in queries],
+                [self.store] * len(queries),
+                [self.store.epoch] * len(queries),
+                words=self.store.words,
+                interpret=self.device.interpret,
+                runner_cache=self._runner_cache,
+                extras_cache=self._extras_cache,
+                pad=self.device.pad_signatures,
+                dedup_keys=None if cse is None else list(cse.dedup_keys),
+                shared_execs=() if cse is None else cse.shared_execs,
+            )
+            self._flush_programs[key] = program
+            self.telemetry.count("h2d_bytes", program.upload_bytes)
+        return cse, program
 
     def serve(self, queries: list[Query]) -> list[QueryResult]:
         """Submit + flush until drained; results in submission order."""
