@@ -18,11 +18,11 @@ can inject modelled bit errors (``repro.core.reliability``); ESP pages are
 error-free — the paper's headline reliability result.
 
 Page data lives in a :class:`repro.core.store.PackedStore` — one contiguous
-``(slots, words)`` array — so sensing is a *gather* of the command's
-wordline rows plus at most two fused MWS kernel dispatches (AND within
-blocks, OR across blocks), never a Python loop over pages.  The ragged
-per-block wordline sets are padded to a rectangle with the store's all-ones
-identity row, letting one kernel call cover every target block.
+``(slots, rows, 128)`` array — so sensing is at most two fused MWS kernel
+dispatches (AND within blocks, OR across blocks) over the command's
+wordline rows, never a Python loop over pages.  The ragged per-block
+wordline sets are padded to a rectangle with the store's all-ones identity
+row, letting one kernel call cover every target block.
 """
 
 from __future__ import annotations
@@ -53,7 +53,7 @@ from repro.core.reliability import (
     rber,
 )
 from repro.core.store import IDENTITY_SLOT, PackedStore
-from repro.kernels.mws.mws import mws_block_reduce_pallas
+from repro.kernels.mws.mws import mws_block_reduce_pallas, mws_sense_pallas
 from repro.kernels.platform import resolve_interpret
 from repro.kernels.threshold import bitslice_threshold, threshold_reduce
 
@@ -103,6 +103,41 @@ def fused_block_reduce(
     else:
         raw = mws_block_reduce_pallas(cube, or_blocks=True)
     return ~raw if inverse else raw
+
+
+def take_pages(data: jax.Array, idx) -> jax.Array:
+    """Gather slot pages of a ``(slots, rows, 128)`` snapshot as flat word
+    rows: ``idx.shape + (rows * 128,)``.  Free on the CPU; on a TPU the
+    flattening relays out only the gathered pages."""
+    pages = jnp.take(data, idx, axis=0, mode="clip")
+    return pages.reshape(pages.shape[: -2] + (-1,))
+
+
+def mws_sense(
+    idx: jax.Array,
+    data: jax.Array,
+    inverse: bool,
+    latch: jax.Array | None = None,
+    *,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """MWS of every member of a ``(members, blocks, wordlines)`` slot table,
+    read in place from a ``(slots, rows, 128)`` snapshot; returns
+    ``(members, rows, 128)``, ANDed into ``latch`` when given (the S latch
+    of a sensing that does not initialize it).
+
+    On a TPU one Pallas dispatch reads each operand page straight from the
+    store by slot (:func:`mws_sense_pallas`): no cube is gathered.  The
+    emulation gathers each member's cube and folds it with
+    :func:`fused_block_reduce` — bit-identical (the kernel tests assert
+    exactly that).
+    """
+    if not resolve_interpret(interpret):
+        return mws_sense_pallas(idx, data, latch, inverse=inverse)
+    raw = jax.vmap(
+        lambda ix: fused_block_reduce(take_pages(data, ix), inverse, interpret=True)
+    )(idx).reshape(idx.shape[:1] + data.shape[1:])
+    return raw if latch is None else latch & raw
 
 
 def threshold_block_reduce(
@@ -316,7 +351,7 @@ class FlashArray:
                     noisy.append((bi, len(row) - 1, name))
             row.extend([IDENTITY_SLOT] * (n_max - len(row)))
             idx.append(row)
-        cube = snap[jnp.asarray(idx)]
+        cube = take_pages(snap, jnp.asarray(idx))
         for bi, wi, name in subs:
             cube = cube.at[bi, wi].set(scratch[name])
         for bi, wi, name in noisy:

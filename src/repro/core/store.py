@@ -5,9 +5,9 @@ Python loop over pages.  :class:`PackedStore` packs every programmed page
 into one contiguous ``(planes, slots, words_per_plane)`` buffer — the layout
 analogue of a multi-plane NAND die where a logical bit vector is striped
 across planes and every plane holds the same (block, wordline) grid.  An
-MWS command then becomes a *gather* of slot rows plus one fused kernel
-dispatch over the whole word axis (= all planes at once), instead of one
-Python-level reduce per page.
+MWS command then becomes one fused kernel dispatch over the whole word axis
+(= all planes at once) that reads its operand slots in place, instead of
+one Python-level reduce per page.
 
 Slot 0 is reserved for an all-ones row: the AND identity used to pad the
 ragged per-block wordline sets of an inter-block MWS to a rectangle, so a
@@ -18,7 +18,10 @@ a plan with fewer target blocks into a wider signature's shape.
 
 Writes append to a host-side ``numpy`` buffer (amortized doubling); the
 device-side ``jax`` snapshot is materialized lazily and invalidated on
-write, so steady-state query serving gathers from one cached array.
+write, so steady-state query serving reads one cached array.  Each slot
+row is padded to whole sensing tiles and the snapshot is shaped
+``(slots, rows, 128)``: on a TPU every page is then one contiguous run of
+``(8, 128)`` tiles, which the in-place sensing kernel reads by slot.
 """
 
 from __future__ import annotations
@@ -27,6 +30,8 @@ from dataclasses import dataclass, field
 
 import jax
 import numpy as np
+
+from repro.kernels.mws.mws import sense_tiles
 
 IDENTITY_SLOT = 0  # all-ones row (AND identity / pad row), always present
 ZERO_SLOT = 1  # all-zeros row (OR identity: pads whole blocks), always present
@@ -57,7 +62,8 @@ class PackedStore:
     All pages share one word count ``W`` (fixed by the first write); each
     page occupies one *slot* of ``planes * words_per_plane`` words, where
     ``words_per_plane = ceil(W / planes)`` (tail padding is sliced off on
-    read).
+    read).  A slot row holds :attr:`page_rows` ``* 128`` words: the planes
+    padded on to whole sensing tiles.
     """
 
     planes: int = 1
@@ -105,6 +111,12 @@ class PackedStore:
         return self.padded_words // self.planes
 
     @property
+    def page_rows(self) -> int:
+        """Rows of 128 words per slot in the snapshot (whole sensing tiles)."""
+        tile, tiles = sense_tiles(self.padded_words)
+        return tile * tiles
+
+    @property
     def num_slots(self) -> int:
         return self._n
 
@@ -119,8 +131,7 @@ class PackedStore:
         if self._buf is not None:
             return
         self._words = words
-        wp = self.padded_words
-        self._buf = np.empty((16, wp), dtype=np.uint32)
+        self._buf = np.empty((16, self.page_rows * 128), dtype=np.uint32)
         self._buf[IDENTITY_SLOT] = _ONES  # AND identity row
         self._buf[ZERO_SLOT] = 0  # OR identity row (block padding)
         self._n = 2
@@ -133,7 +144,7 @@ class PackedStore:
                 f"page {name!r} has {w.shape[0]} words, store fixed at "
                 f"{self._words}"
             )
-        row = np.zeros((self.padded_words,), dtype=np.uint32)
+        row = np.zeros((self._buf.shape[1],), dtype=np.uint32)
         row[: self._words] = w
         slot = self._slots.get(name)
         if slot is None:
@@ -185,21 +196,23 @@ class PackedStore:
         return jax.device_put(self._buf[slot, : self._words], self.device)
 
     def snapshot(self) -> jax.Array:
-        """Device-side ``(slots, planes * words_per_plane)`` packed array.
+        """Device-side ``(slots, page_rows, 128)`` packed array.
 
-        Cached until the next write; a multi-plane gather + reduce over this
-        array covers every plane in one kernel dispatch because planes are
-        word-axis shards of each slot row.
+        Cached until the next write; one sensing over this array covers
+        every plane in one kernel dispatch because planes are word-axis
+        shards of each slot row.  Row-major, so the host buffer reshapes
+        for free and is uploaded as it is.
         """
         if self._snapshot is None:
             assert self._buf is not None, "empty store has no snapshot"
-            self._snapshot = jax.device_put(self._buf[: self._n], self.device)
+            rows = self._buf[: self._n].reshape(self._n, self.page_rows, 128)
+            self._snapshot = jax.device_put(rows, self.device)
             self.snapshot_uploads += 1
         return self._snapshot
 
     def plane_view(self) -> jax.Array:
-        """The same data as ``(planes, slots, words_per_plane)``."""
-        snap = self.snapshot()
+        """The data as ``(planes, slots, words_per_plane)``."""
+        snap = self.snapshot().reshape(self._n, -1)[:, : self.padded_words]
         return snap.reshape(self._n, self.planes, self.words_per_plane).swapaxes(
             0, 1
         )

@@ -28,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitops import BitOp
 
@@ -193,3 +194,126 @@ def mws_block_reduce_pallas(
         name="flashql_mws_block_reduce",
     )(cube)
     return out[0] if or_blocks else out[:, 0]
+
+
+# In-place sensing reads each operand page as (rows, 128) tiles of at most
+# this many rows: (4096, 128) uint32 = 2 MiB, so the double-buffered
+# operand, S latch and result tiles and a block's scratch tile take at
+# most 14 MiB of VMEM.
+SENSE_TILE_ROWS = 4096
+
+
+def sense_tiles(words: int) -> tuple[int, int]:
+    """``(tile rows, row tiles)`` of a ``words``-word page laid out as
+    ``(rows, 128)``: the fewest tiles of at most :data:`SENSE_TILE_ROWS`
+    rows, each a multiple of 8 rows.  A store pads its pages to
+    ``tile rows * row tiles * 128`` words, which wastes under 8 rows per
+    tile."""
+    rows = -(-words // 128)
+    n = max(1, -(-rows // SENSE_TILE_ROWS))
+    return 8 * -(-rows // (8 * n)), n
+
+
+def _sense_kernel(ix_ref, x_ref, *refs, inverse, blocks, wls, latch):
+    s_ref = refs[0] if latch else None  # the S latch the result ANDs into
+    o_ref = refs[int(latch)]
+    b = pl.program_id(2)  # NAND block
+    w = pl.program_id(3)  # wordline (innermost)
+    # one block ANDs straight into the result; several AND in a scratch
+    # tile and OR into the result as each block's last wordline lands
+    acc = refs[int(latch) + 1] if blocks > 1 else o_ref
+    last = w == wls - 1
+
+    @pl.when(w == 0)
+    def _first():
+        acc[...] = x_ref[...]
+
+    @pl.when(w > 0)
+    def _and():
+        acc[...] = acc[...] & x_ref[...]
+
+    if blocks > 1:
+
+        @pl.when(last & (b == 0))
+        def _set():
+            o_ref[...] = acc[...]
+
+        @pl.when(last & (b > 0))
+        def _or():
+            o_ref[...] = o_ref[...] | acc[...]
+
+    if inverse or latch:
+
+        @pl.when(last & (b == blocks - 1))
+        def _finish():
+            out = ~o_ref[...] if inverse else o_ref[...]
+            o_ref[...] = out if s_ref is None else out & s_ref[...]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("inverse", "tile_rows", "interpret")
+)
+def mws_sense_pallas(
+    idx: jax.Array,
+    store: jax.Array,
+    latch: jax.Array | None = None,
+    *,
+    inverse: bool,
+    tile_rows: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """MWS of every member straight from the store, with no gathered cube.
+
+    ``idx`` is the ``(members, blocks, wordlines)`` int32 slot table and
+    ``store`` the ``(slots, rows, 128)`` uint32 page store, each page one
+    contiguous run of ``(8, 128)`` tiles.  The slot table is prefetched to
+    scalar memory, and each grid step ``(member, row tile, block,
+    wordline)`` DMAs one operand tile by its slot: the NAND string ANDs a
+    block's wordlines, the shared bitlines OR the blocks, and an inverse
+    read complements once at the end.  A step whose slot repeats the
+    previous step's skips the copy, so padded wordlines that repeat their
+    block's last slot read nothing.  ``latch`` (``(members, rows, 128)``)
+    is the S latch the result ANDs into (a sensing that does not
+    initialize it).  Returns ``(members, rows, 128)``, each member's
+    result written once.
+    """
+    m, k, n = idx.shape
+    _, rows, lanes = store.shape
+    if tile_rows is None:
+        tile_rows, _ = sense_tiles(rows * lanes)
+    assert lanes == 128 and rows % tile_rows == 0, (store.shape, tile_rows)
+    tile = (None, tile_rows, lanes)
+    tile_bytes = tile_rows * lanes * store.dtype.itemsize
+    latches = () if latch is None else (latch,)
+    buffers = 4 + 2 * len(latches) + (k > 1)
+    operand = pl.BlockSpec(
+        tile, lambda i, r, b, w, ix: (ix[(i * k + b) * n + w], r, 0)
+    )
+    result = pl.BlockSpec(tile, lambda i, r, b, w, ix: (i, r, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _sense_kernel,
+            inverse=inverse,
+            blocks=k,
+            wls=n,
+            latch=bool(latches),
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m, rows // tile_rows, k, n),
+            in_specs=[operand] + [result] * len(latches),
+            out_specs=result,
+            scratch_shapes=(
+                [pltpu.VMEM((tile_rows, lanes), store.dtype)] if k > 1 else []
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, rows, lanes), store.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+            # double-buffered operand, S latch and result tiles, the block
+            # scratch tile, and head-room
+            vmem_limit_bytes=max(16 << 20, tile_bytes * buffers + (4 << 20)),
+        ),
+        interpret=interpret,
+        name="flashql_mws_sense",
+    )(idx.reshape(-1).astype(jnp.int32), store, *latches)

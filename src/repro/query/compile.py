@@ -93,7 +93,12 @@ from repro.query.bitmap import (
     bsi_page,
     eq_page,
 )
-from repro.query.device import group_execs, make_flush_runner
+from repro.query.device import (
+    group_execs,
+    make_flush_runner,
+    sense_idxs,
+    sensings,
+)
 from repro.query.optimize import best_plan
 
 
@@ -603,8 +608,9 @@ class FlushProgram:
     # (B,) int32 member gather over the sensed rows: with whole-plan dedup
     # it maps each member onto its unique representative's row (duplicate
     # queries read one sensing's output), without dedup it is the plain
-    # concat-order -> member-order inverse permutation
-    inv_perm: jax.Array
+    # concat-order -> member-order inverse permutation; None where that is
+    # the identity, so the program gathers nothing
+    inv_perm: jax.Array | None
     sels: tuple  # per reduce group: (B_r,) member gather, or None if all
     extras: tuple  # per reduce group: (B_r, P, W) plane stack, or None
     reduce_parse: tuple  # per reduce group: (member tuple, payload leaves)
@@ -613,6 +619,9 @@ class FlushProgram:
     # host->device bytes the build copied: index arrays, member gathers and
     # extra-plane stacks fetched from host pages
     upload_bytes: int = 0
+    # (in place, gathered): MWS steps x members each run senses, shared
+    # plans included (see repro.query.device.sensings)
+    sensings: tuple[int, int] = (0, 0)
 
     def run(self, data: jax.Array, mask: jax.Array) -> jax.Array:
         """Dispatch the fused program (async); returns the device payload."""
@@ -759,9 +768,13 @@ def compile_flush(
             runner_cache.clear()
         runner = make_flush_runner(key, interpret)
         runner_cache[rkey] = runner
-    inv_perm = jnp.asarray(inv)
+    # members that read the sensed rows in order need no member gather
+    inv_perm = (
+        None if np.array_equal(inv, np.arange(n)) else jnp.asarray(inv)
+    )
     cse_idxs = tuple(
-        tuple(jnp.asarray(x) for x in e.idxs) for e in shared_execs
+        tuple(jnp.asarray(x) for x in sense_idxs(e.signature, e.idxs))
+        for e in shared_execs
     )
     uploaded += sum(
         a.nbytes
@@ -781,4 +794,5 @@ def compile_flush(
         extra_counts=tuple(extra_counts),
         cse_idxs=cse_idxs,
         upload_bytes=int(uploaded),
+        sensings=sensings(sense + [(sig, 1) for sig in cse]),
     )

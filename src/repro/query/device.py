@@ -12,8 +12,10 @@ Python loop over commands.  ``FlashDevice`` extends it for query serving:
 * a :class:`CommandPlan` compiles to an :class:`ExecPlan`: per MWS command
   a static ``(blocks, wordlines)`` slot-index array (ragged wordline sets
   padded with the store's all-ones identity slot) plus the static ISCM
-  flags.  Executing is then pure array code — gather, fused reduce, latch
-  algebra — with **no Python-level per-page work**;
+  flags.  Executing is then pure array code — a plain MWS step senses its
+  operand slots in place (:func:`repro.core.engine.mws_sense`), threshold
+  and substituting steps gather a cube and reduce it, then latch algebra —
+  with **no Python-level per-page work**;
 * plans with identical *signatures* (same command structure and shapes,
   different slot indices) execute as one batch under ``jax.vmap``: the
   whole batch becomes a handful of kernel dispatches regardless of batch
@@ -57,9 +59,12 @@ from repro.core.commands import (
 from repro.core.engine import (
     FlashArray,
     fused_block_reduce,
+    mws_sense,
+    take_pages,
     threshold_block_reduce,
 )
 from repro.core.store import IDENTITY_SLOT, ZERO_SLOT, PackedStore
+from repro.kernels.platform import resolve_interpret
 
 
 @dataclass(frozen=True)
@@ -198,10 +203,13 @@ def group_execs(
             replace(st, shape=next(it)) if st.kind == "mws" else st
             for st in first.steps
         )
-        stacked = [
-            np.stack([pad_idx(execs[i].idxs[s], shapes[s]) for i in members])
-            for s in range(n_mws)
-        ]
+        stacked = sense_idxs(
+            signature,
+            [
+                np.stack([pad_idx(execs[i].idxs[s], shapes[s]) for i in members])
+                for s in range(n_mws)
+            ],
+        )
         out.append((signature, members, stacked))
     return out
 
@@ -215,60 +223,170 @@ def reorder_rows(pieces: list[jax.Array], order: list[int]) -> jax.Array:
     return allout[jnp.asarray(inv)]
 
 
-def plan_step_fn(signature: tuple[_Step, ...], interpret: bool | None):
-    """Pure single-plan executor for one signature:
-    ``run_one(data, shared, *idxs)``.
+def senses_in_place(st: _Step) -> bool:
+    """A plain MWS step (no threshold, no spill or CSE substitution) reads
+    its operand pages straight from the store (:func:`mws_sense`); the
+    others gather a cube first."""
+    return st.kind == "mws" and not st.k and not st.subs and not st.shared
 
-    The traced body shared by :func:`make_plan_runner` (standalone jitted
-    vmap) and :func:`make_flush_runner` (inlined into the fused flush
-    program).  ``"spill"`` steps park the latch value in a plan-local
-    scratch list; MWS steps with substitutions splice those values into the
-    gathered cube at static positions (device-resident scratch — spilling
-    plans never touch the store).  ``shared`` is the flush-level CSE value
-    stack (``(K, words)`` or None): MWS steps carrying ``shared``
-    substitutions splice those rows in the same way, fanning one sensing's
-    latch result out to every plan that references it.
-    """
 
-    def run_one(data: jax.Array, shared, *idxs: jax.Array) -> jax.Array:
-        s = c = out = None
-        scratch: list[jax.Array] = []
-        it = iter(idxs)
+def repeat_padding(idx: np.ndarray) -> np.ndarray:
+    """Padded wordlines repeat their block's previous slot instead of the
+    identity slot.  AND is idempotent, so the result is unchanged, and the
+    in-place kernel skips a copy whose slot repeats: padding reads no page.
+    (Slot 0 is only ever padding in an in-place step.)"""
+    out = np.array(idx, dtype=np.int32)
+    for w in range(1, out.shape[-1]):
+        pad = out[..., w] == IDENTITY_SLOT
+        out[..., w] = np.where(pad, out[..., w - 1], out[..., w])
+    return out
+
+
+def sense_idxs(signature: tuple[_Step, ...], idxs) -> list[np.ndarray]:
+    """A plan's (or stacked group's) slot tables as its steps read them:
+    :func:`repeat_padding` on each in-place step's table."""
+    steps = [st for st in signature if st.kind == "mws"]
+    return [
+        repeat_padding(ix) if senses_in_place(st) else ix
+        for st, ix in zip(steps, idxs)
+    ]
+
+
+def sensings(groups) -> tuple[int, int]:
+    """``(in place, gathered)`` MWS sensings of ``(signature, members)``
+    groups: MWS steps x members, as the static steps say (the emulation of
+    an in-place step folds a gathered cube, bit-identical to the kernel)."""
+    placed = gathered = 0
+    for signature, n in groups:
         for st in signature:
             if st.kind == "mws":
-                # (blocks, wordlines, words); take, not data[idx]: it
-                # traces several times cheaper inside vmapped programs
-                cube = jnp.take(data, next(it), axis=0, mode="clip")
-                for bi, wi, o in st.subs:
-                    cube = cube.at[bi, wi].set(scratch[o])
-                for bi, wi, k in st.shared:
-                    cube = cube.at[bi, wi].set(shared[k])
-                raw = (
-                    threshold_block_reduce(
-                        cube, st.k, st.inverse, interpret=interpret
-                    )
-                    if st.k
-                    else fused_block_reduce(
-                        cube, st.inverse, interpret=interpret
-                    )
-                )
-                s = raw if (st.init_s or s is None) else s & raw
-                if st.init_c:
-                    c = None
-                if st.move:
-                    c = s if c is None else c | s
-            elif st.kind == "spill":
-                assert st.ordinal == len(scratch)
-                scratch.append(s if st.source == "S" else c)
-            elif st.kind == "xor":
-                c = s ^ c
-            else:
-                val = s if st.source == "S" else c
-                out = ~val if st.invert else val
-        assert out is not None, "plan missing TransferCommand"
-        return out
+                if senses_in_place(st):
+                    placed += n
+                else:
+                    gathered += n
+    return placed, gathered
+
+
+def _run_plan(signature: tuple[_Step, ...], idxs, sense):
+    """The latch algebra of one signature.  ``sense(st, idx, keep,
+    scratch)`` returns the S latch after MWS step ``st``: its sensing ANDed
+    into ``keep``, or alone where the step initializes S (``keep`` None).
+    ``"spill"`` steps park the latch value in a plan-local scratch list,
+    which MWS steps with substitutions splice into their gathered cube
+    (device-resident scratch — spilling plans never touch the store)."""
+    s = c = out = None
+    scratch: list[jax.Array] = []
+    it = iter(idxs)
+    for st in signature:
+        if st.kind == "mws":
+            s = sense(st, next(it), None if st.init_s else s, scratch)
+            if st.init_c:
+                c = None
+            if st.move:
+                c = s if c is None else c | s
+        elif st.kind == "spill":
+            assert st.ordinal == len(scratch)
+            scratch.append(s if st.source == "S" else c)
+        elif st.kind == "xor":
+            c = s ^ c
+        else:
+            val = s if st.source == "S" else c
+            out = ~val if st.invert else val
+    assert out is not None, "plan missing TransferCommand"
+    return out
+
+
+def _sense_gathered(st: _Step, cube, shared, interpret, scratch):
+    """One plan's MWS step over its gathered ``(blocks, wls, words)`` cube,
+    with spilled (``scratch``) and CSE-shared values spliced in at their
+    static positions; returns the ``(words,)`` raw sensing."""
+    for bi, wi, o in st.subs:
+        cube = cube.at[bi, wi].set(scratch[o].reshape(-1))
+    for bi, wi, k in st.shared:
+        cube = cube.at[bi, wi].set(shared[k].reshape(-1))
+    if st.k:
+        return threshold_block_reduce(cube, st.k, st.inverse, interpret=interpret)
+    return fused_block_reduce(cube, st.inverse, interpret=interpret)
+
+
+def plan_step_fn(signature: tuple[_Step, ...], interpret: bool | None):
+    """Pure single-plan executor for one signature:
+    ``run_one(data, shared, *idxs)`` with one ``(blocks, wordlines)`` slot
+    table per MWS step; returns ``(rows * 128,)`` words.
+
+    Every MWS step gathers its cube and reduces it; callers vmap it over a
+    group's members (the emulation, and a fleet's stacked snapshot, where
+    each member reads its own shard).  ``shared`` is the flush-level CSE
+    value stack (``(K, words)`` or None): MWS steps carrying ``shared``
+    substitutions splice those rows in, fanning one sensing's latch result
+    out to every plan that references it.
+    """
+    emulate = resolve_interpret(interpret)
+
+    def run_one(data: jax.Array, shared, *idxs: jax.Array) -> jax.Array:
+        # one flat view of the store where that is free (the emulation);
+        # a chip flattens only the gathered pages
+        flat = data.reshape(data.shape[0], -1) if emulate else None
+
+        def sense(st, idx, keep, scratch):
+            cube = (
+                take_pages(data, idx)
+                if flat is None
+                else jnp.take(flat, idx, axis=0, mode="clip")
+            )
+            raw = _sense_gathered(st, cube, shared, interpret, scratch)
+            return raw if keep is None else keep & raw
+
+        return _run_plan(signature, idxs, sense)
 
     return run_one
+
+
+def plan_batch_fn(signature: tuple[_Step, ...], interpret: bool | None):
+    """Batched executor for one signature: ``run(data, shared, *idxs)``
+    with one ``(members, blocks, wordlines)`` slot table per MWS step;
+    returns ``(members, rows, 128)``, pages shaped as the snapshot's.
+
+    Plain MWS steps (:func:`senses_in_place`) read their operands in place,
+    one dispatch for every member, and AND straight into the S latch they
+    do not initialize (:func:`mws_sense`); threshold and substituting steps
+    gather each member's cube as :func:`plan_step_fn` does.
+    """
+
+    def run(data: jax.Array, shared, *idxs: jax.Array) -> jax.Array:
+        def sense(st, idx, keep, scratch):
+            if senses_in_place(st):
+                return mws_sense(idx, data, st.inverse, keep, interpret=interpret)
+            raw = jax.vmap(
+                lambda ix, scr: _sense_gathered(
+                    st, take_pages(data, ix), shared, interpret, scr
+                )
+            )(idx, tuple(scratch)).reshape(idx.shape[:1] + data.shape[1:])
+            return raw if keep is None else keep & raw
+
+        return _run_plan(signature, idxs, sense)
+
+    return run
+
+
+def group_fn(signature: tuple[_Step, ...], interpret: bool | None):
+    """``run(data, shared, *idxs)`` -> ``(members, rows * 128)`` for one
+    sense group: the batched in-place executor where the kernels are
+    compiled; where the platform emulates them, the single-plan folds
+    vmapped over the members (bit-identical, and far cheaper to trace)."""
+    if resolve_interpret(interpret):
+        n_mws = sum(1 for st in signature if st.kind == "mws")
+        return jax.vmap(
+            plan_step_fn(signature, interpret),
+            in_axes=(None, None) + (0,) * n_mws,
+        )
+    run = plan_batch_fn(signature, interpret)
+
+    def flat(data, shared, *idxs):
+        out = run(data, shared, *idxs)
+        return out.reshape(out.shape[0], -1)
+
+    return flat
 
 
 def make_plan_runner(
@@ -277,31 +395,29 @@ def make_plan_runner(
     *,
     shard_data: bool = False,
 ):
-    """Build the jitted vmap executor for one plan signature.
+    """Build the jitted batch executor for one plan signature; it returns
+    ``(batch, rows * 128)`` words.
 
-    ``shard_data=False``: ``run(data, *idxs)`` with one ``(slots, words)``
-    snapshot shared by every batch element (single device).
+    ``shard_data=False``: ``run(data, *idxs)`` with one ``(slots, rows,
+    128)`` snapshot shared by every batch element (single device).
 
     ``shard_data=True``: ``run(data, shard_ix, *idxs)`` where ``data`` is a
-    stacked ``(shards, slots, words)`` fleet snapshot and ``shard_ix`` maps
-    each batch element to its shard — one jit-of-vmap dispatch covers a
-    whole signature group across every device of a sharded deployment.
+    stacked ``(shards, slots, rows, 128)`` fleet snapshot and ``shard_ix``
+    maps each batch element to its shard — one jit-of-vmap dispatch covers
+    a whole signature group across every device of a sharded deployment.
+    Its steps gather (each member reads its own shard).
     """
-    run_one = plan_step_fn(signature, interpret)
-    n_mws = sum(1 for st in signature if st.kind == "mws")
     if shard_data:
+        run_one = plan_step_fn(signature, interpret)
+        n_mws = sum(1 for st in signature if st.kind == "mws")
         return jax.jit(
             jax.vmap(
                 lambda data, si, *ix: run_one(data[si], None, *ix),
                 in_axes=(None, 0) + (0,) * n_mws,
             )
         )
-    return jax.jit(
-        jax.vmap(
-            lambda data, *ix: run_one(data, None, *ix),
-            in_axes=(None,) + (0,) * n_mws,
-        )
-    )
+    run = group_fn(signature, interpret)
+    return jax.jit(lambda data, *ix: run(data, None, *ix))
 
 
 def make_flush_runner(key: tuple, interpret: bool | None):
@@ -335,23 +451,23 @@ def make_flush_runner(key: tuple, interpret: bool | None):
         with jax.named_scope("sense"):
             shared = None
             if cse:
-                # shared subexpressions first: K is small, so these run as
-                # plain (unvmapped) plans; members gather their rows below
+                # shared subexpressions first, as groups of one; members
+                # splice their rows in below
                 vals = [
-                    plan_step_fn(psig, interpret)(data, None, *idxs)
+                    group_fn(psig, interpret)(
+                        data, None, *(i[None] for i in idxs)
+                    )[0]
                     for psig, idxs in zip(cse, cse_idxs)
                 ]
                 shared = jnp.stack(vals)
-            pieces = []
-            for (psig, _n), idxs in zip(sense, group_idxs):
-                one = plan_step_fn(psig, interpret)
-                n_mws = len(idxs)
-                out = jax.vmap(one, in_axes=(None, None) + (0,) * n_mws)(
-                    data, shared, *idxs
-                )
-                pieces.append(out[:, :w])
+            pieces = [
+                group_fn(psig, interpret)(data, shared, *idxs)[:, :w]
+                for (psig, _n), idxs in zip(sense, group_idxs)
+            ]
             allout = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
-            masked = allout[inv_perm] & mask  # member order, padding zeroed
+            if inv_perm is not None:  # member order (see compile_flush)
+                allout = allout[inv_perm]
+            masked = allout & mask  # padding zeroed
         with jax.named_scope("reduce"):
             parts = []
             for (kind, sig, _n, _p), sel, ex in zip(reduce_sigs, sels, extras):
@@ -586,6 +702,12 @@ class FlashDevice(FlashArray):
             execs = [self.build_exec(p) for p in plans]
         groups, eager_ix = self._prepare_batch(execs, batch_key)
         self.last_signature_groups = len(groups)
+        if self.telemetry is not None:
+            placed, gathered = sensings(
+                (sig, len(members)) for sig, members, _ in groups
+            )
+            self.telemetry.count("mws_sensed_in_place", placed)
+            self.telemetry.count("mws_sensed_gathered", gathered)
         self.last_eager_plans = len(eager_ix) + sum(
             1 for e in execs if e is None
         )

@@ -743,6 +743,9 @@ class BatchScheduler:
                     tele.count("h2d_bytes", data.nbytes)
                 with tele.stage("launch", flush=fid):
                     payload = program.run(data, mask_words)
+                placed, gathered = program.sensings
+                tele.count("mws_sensed_in_place", placed)
+                tele.count("mws_sensed_gathered", gathered)
                 if cse is None:
                     age_spill_blocks(self.device.pec, execs)
                 else:

@@ -91,6 +91,7 @@ from repro.query.device import (
     group_execs,
     make_plan_runner,
     reorder_rows,
+    sensings,
 )
 from repro.query.optimize import cse_flush
 from repro.query.scheduler import (
@@ -1448,6 +1449,9 @@ class ShardedFlashQL:
             tele.count("cse_rewritten_members", cse.n_rewritten)
             tele.count("cse_spill_programs", len(cse.shared_plans))
             tele.count(f"shard{s}.cse_esp_programs", len(cse.shared_plans))
+        placed, gathered = program.sensings
+        tele.count("mws_sensed_in_place", placed)
+        tele.count("mws_sensed_gathered", gathered)
         tele.count("fused_dispatches")
         tele.count(f"shard{s}.fused_dispatches")
         tele.count("signature_groups", program.n_sense_groups)
@@ -1644,6 +1648,11 @@ class ShardedFlashQL:
                         self._group_cache.clear()
                     self._group_cache[cache_key] = prepared
                 tele.count("signature_groups", len(prepared))
+                # the stacked snapshot's runners gather every step
+                tele.count(
+                    "mws_sensed_gathered",
+                    sum(sensings((sig, len(m)) for sig, _, _, m in prepared)),
+                )
                 for signature, fleet_ix, idxs, members in prepared:
                     out = self._sharded_runner(signature)(
                         data, fleet_ix, *idxs

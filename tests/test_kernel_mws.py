@@ -13,8 +13,10 @@ from _hypothesis_compat import given, settings, st  # hypothesis optional
 from repro.core.bitops import BitOp, pack_bits, reduce_words, unpack_bits
 from repro.core.engine import fused_block_reduce, threshold_block_reduce
 from repro.kernels.mws import mws_reduce, mws_reduce_ref, parabit_reduce
-from repro.kernels.mws.mws import mws_block_reduce_pallas
+from repro.core.store import IDENTITY_SLOT, ZERO_SLOT
+from repro.kernels.mws.mws import mws_block_reduce_pallas, mws_sense_pallas
 from repro.kernels.popcount import popcount
+from repro.query.device import pad_idx, repeat_padding
 
 ALL_OPS = list(BitOp)
 
@@ -177,3 +179,67 @@ def test_default_kernel_path_on_cpu_is_emulation():
         np.asarray(jax.jit(lambda c: threshold_block_reduce(c, 2, False))(cube)),
         np.asarray(threshold_block_reduce(cube, 2, False, interpret=True)),
     )
+
+
+def _sense_idx(rng, members, shape, wls, slots):
+    """``members`` slot tables of ``shape`` (blocks, wordlines), each block
+    holding ``wls[b]`` real slots and the store's identity padding, padded
+    on to ``shape`` as plan-aware batching pads them."""
+    real, width = len(wls), max(wls)
+    tables = []
+    for _ in range(members):
+        ix = np.full((real, width), IDENTITY_SLOT, np.int32)
+        for b, n in enumerate(wls):
+            ix[b, :n] = rng.integers(ZERO_SLOT + 1, slots, n)
+        tables.append(pad_idx(ix, shape))
+    return np.stack(tables)
+
+
+@pytest.mark.parametrize(
+    "members,shape,wls,inverse,words,latch",
+    [
+        (1, (1, 1), (1,), False, 1024, False),  # one page
+        (8, (1, 29), (21,), False, 2500, True),  # identity-padded long AND
+        (8, (1, 3), (3,), True, 3000, False),  # inverse read, one block
+        (3, (3, 4), (4, 2, 3), False, 1100, False),  # several blocks
+        (2, (4, 5), (5, 1), True, 2500, True),  # zero-slot-padded blocks
+        (1, (2, 2), (2, 2), True, 3 * 1024, False),  # words fill the tiles
+    ],
+)
+def test_mws_sense_matches_gathered_fold(members, shape, wls, inverse, words, latch):
+    """In-place sensing (Pallas interpreter) equals the fold of the cube
+    gathered from the same store, bit for bit: AND within blocks, OR
+    across blocks, identity and zero padding, inverse read, the S latch,
+    and word counts that leave a tail in the last tile (sliced off)."""
+    rng = np.random.default_rng(members * 1000 + words)
+    slots = 9
+    flat = np.array(_rand_stack(rng, slots, words, jnp.uint32))
+    flat[IDENTITY_SLOT] = 0xFFFFFFFF
+    flat[ZERO_SLOT] = 0
+    rows = 8 * -(-words // 1024)  # whole (8, 128) tiles, one per grid row
+    store = np.zeros((slots, rows * 128), np.uint32)
+    store[:, :words] = flat
+    store = jnp.asarray(store.reshape(slots, rows, 128))
+    idx = _sense_idx(rng, members, shape, wls, slots)
+    s = _rand_stack(rng, members, rows * 128, jnp.uint32).reshape(members, rows, 128)
+
+    want = jax.vmap(
+        lambda ix: fused_block_reduce(
+            jnp.take(jnp.asarray(flat), ix, axis=0), inverse, interpret=True
+        )
+    )(jnp.asarray(idx))
+    if latch:
+        want = want & s.reshape(members, -1)[:, :words]
+    for table in (idx, repeat_padding(idx)):  # as planned, as the runner sends
+        got = mws_sense_pallas(
+            jnp.asarray(table),
+            store,
+            s if latch else None,
+            inverse=inverse,
+            tile_rows=8,
+            interpret=True,
+        )
+        assert got.shape == (members, rows, 128)
+        np.testing.assert_array_equal(
+            np.asarray(got).reshape(members, -1)[:, :words], np.asarray(want)
+        )

@@ -3,6 +3,7 @@ oracle bit-exactly (error injection disabled — every page ESP-programmed),
 plus targeted coverage for planner spill paths, ``auto_layout``, the packed
 store, and the plan cache."""
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -16,6 +17,7 @@ from repro.core.store import PackedStore
 from repro.query import (
     VALID_PAGE,
     Agg,
+    AtLeast,
     BatchScheduler,
     BitmapStore,
     Eq,
@@ -488,3 +490,45 @@ def test_served_default_path_resolves_to_emulation_on_cpu():
     mask = jnp.asarray(store.valid_words_mask())
     hlo = sched.last_program.lower(dev.store.snapshot(), mask).compile().as_text()
     assert "tpu_custom_call" not in hlo
+
+
+def test_batched_executor_matches_per_plan_folds():
+    """The executor a TPU runs (in-place sensing for plain MWS steps, one
+    dispatch per group, the S latch ANDed in the sensing) equals the
+    per-plan folds the emulation vmaps, on every group of a mix of plain,
+    spilling (deep range) and threshold plans — here with the sensing
+    emulated, so the latch algebra of both is compared on the CPU."""
+    from repro.query.device import group_execs, plan_batch_fn, plan_step_fn
+
+    rng = np.random.default_rng(31)
+    t = _rand_table(rng, 1500)
+    store = BitmapStore()
+    store.ingest(t)
+    dev = FlashDevice(num_planes=2)
+    store.program(dev)
+    compiler = QueryCompiler(store, dev)
+    preds = [
+        qand(In("country", [1, 3, 5]), Eq("device", 2)),
+        qor(Eq("country", 0), Not(Eq("device", 1))),
+        Range("age", 7, 93),  # deep range: spills
+        AtLeast(2, [Eq("country", 1), Eq("device", 3), Range("age", 0, 40)]),
+        AtLeast(3, [Eq("country", c) for c in range(5)]),  # threshold
+    ]
+    execs = [compiler.exec_for(compiler.compile(Query(p))) for p in preds]
+    data = dev.store.snapshot()
+    kinds = set()
+    for sig, members, stacked in group_execs(execs):
+        idxs = [jnp.asarray(x) for x in stacked]
+        want = jax.vmap(
+            plan_step_fn(sig, True), in_axes=(None, None) + (0,) * len(idxs)
+        )(data, None, *idxs)
+        got = plan_batch_fn(sig, True)(data, None, *idxs)
+        assert got.shape == (len(members),) + data.shape[1:]
+        np.testing.assert_array_equal(
+            np.asarray(got).reshape(len(members), -1), np.asarray(want)
+        )
+        kinds |= {(st.kind, bool(st.k), bool(st.subs)) for st in sig}
+    # the mix covers plain, spilling and threshold steps
+    assert {("mws", False, False), ("spill", False, False)} <= kinds
+    assert any(k == "mws" and thr for k, thr, _ in kinds)
+    assert any(k == "mws" and subs for k, _, subs in kinds)

@@ -20,11 +20,16 @@ import numpy as np
 import pytest
 
 from repro.core.bitops import BitOp
-from repro.kernels.mws.mws import mws_block_reduce_pallas, mws_reduce_pallas
+from repro.kernels.mws.mws import (
+    mws_block_reduce_pallas,
+    mws_reduce_pallas,
+    mws_sense_pallas,
+)
 from repro.kernels.mws_count.mws_count import mws_count_pallas
 from repro.kernels.popcount.popcount import popcount_pallas
 from repro.kernels.threshold.threshold import threshold_pallas
 from repro.query import (
+    AtLeast,
     BatchScheduler,
     BitmapStore,
     Count,
@@ -40,6 +45,7 @@ from repro.query import (
 )
 from repro.query import telemetry as telemetry_mod
 from repro.query.ast import and_ as qand
+from repro.query.device import senses_in_place
 
 LIFECYCLE = ("compile", "dispatch", "transfer", "reduce")
 NESTED = {
@@ -307,7 +313,65 @@ def _pallas_names(fn, *args):
          (3, 256), "flashql_threshold"),
         (lambda x: mws_count_pallas(x, BitOp.AND, fan_in=8, block_words=128),
          (8, 256), "flashql_mws_count"),
+        (lambda x: mws_sense_pallas(jnp.zeros((2, 1, 3), jnp.int32), x, inverse=True),
+         (4, 8, 128), "flashql_mws_sense"),
     ],
 )
 def test_served_kernels_carry_stable_names(fn, shape, name):
     assert _pallas_names(fn, jnp.zeros(shape, jnp.uint32)) == [name]
+
+
+def _retention_scheduler():
+    rng = np.random.default_rng(5)
+    n = 900
+    table = {f"day{d}": (rng.random(n) < 0.9).astype(np.int64) for d in range(8)}
+    table["country"] = rng.integers(0, 6, n)
+    store = BitmapStore()
+    store.ingest(table)
+    dev = FlashDevice(num_planes=2)
+    days = qand(*(Eq(f"day{d}", 1) for d in range(8)))
+    store.program(dev, warmup=[Query(days)])
+    return BatchScheduler(dev, store)
+
+
+def _static_sensings(program) -> tuple[int, int]:
+    sense, _, _, cse = program.key
+    placed = gathered = 0
+    for sig, n in list(sense) + [(sig, 1) for sig in cse]:
+        for st in sig:
+            if st.kind == "mws":
+                if senses_in_place(st):
+                    placed += n
+                else:
+                    gathered += n
+    return placed, gathered
+
+
+def test_mws_sensings_count_in_place_and_gathered():
+    """A retention-shaped flush (a cohort's country IN, ANDed with a run of
+    days) senses every MWS step in place; a k-of-N threshold sensing is
+    gathered.  Each launched flush adds MWS steps x members, as the flush
+    program's static steps say."""
+    sched = _retention_scheduler()
+    tele = sched.telemetry
+    cohort = In("country", [0, 2, 4])
+    panels = [
+        Query(qand(cohort, *(Eq(f"day{d}", 1) for d in range(8 - m, 8))))
+        for m in (2, 4, 6, 8)
+    ]
+    sched.serve(panels)
+    placed, gathered = _static_sensings(sched.last_program)
+    assert gathered == 0 and placed >= len(panels)
+    assert tele.value("mws_sensed_in_place") == placed
+    assert tele.value("mws_sensed_gathered") == 0
+
+    sched.serve(panels)  # the same composition counts again
+    assert tele.value("mws_sensed_in_place") == 2 * placed
+
+    # pages in five blocks: one native 3-of-5 threshold sensing
+    vote = Query(AtLeast(3, [Eq("country", c) for c in range(5)]))
+    sched.serve([vote])
+    more_placed, more_gathered = _static_sensings(sched.last_program)
+    assert more_gathered >= 1
+    assert tele.value("mws_sensed_in_place") == 2 * placed + more_placed
+    assert tele.value("mws_sensed_gathered") == more_gathered

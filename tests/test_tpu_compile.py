@@ -14,6 +14,8 @@ imports this file.
 """
 
 import importlib.util
+import re
+import sys
 from pathlib import Path
 
 import jax
@@ -25,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.bitops import BitOp, num_words
 from repro.core.engine import fused_block_reduce, threshold_block_reduce
 from repro.kernels.mws import mws_reduce
+from repro.kernels.mws.mws import sense_tiles
 from repro.kernels.mws_count import mws_count
 from repro.kernels.popcount import popcount
 from repro.kernels.threshold import threshold_reduce
@@ -32,6 +35,8 @@ from repro.query.aggregate import sliced_counts
 from repro.query.device import make_flush_runner
 
 REPO = Path(__file__).resolve().parents[1]
+if str(REPO) not in sys.path:  # the benchmark's package, for its cell
+    sys.path.insert(0, str(REPO))
 HBM_BYTES = 16 << 30  # one v5e chip
 PLANES = 4
 
@@ -76,6 +81,11 @@ def one_chip(topo):
 
 def _spec(shape, one_chip, dtype=jnp.uint32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _page_rows(words):
+    tile, tiles = sense_tiles(words)
+    return tile * tiles
 
 
 def _compile_for_chip(fn, *args):
@@ -171,11 +181,74 @@ def test_smoke_flush_program_compiles_and_fits(one_chip):
     )
     _compile_for_chip(
         runner,
-        _spec((slots, W), one_chip),
+        _spec((slots, _page_rows(W), 128), one_chip),
         jax.tree.map(like, program.group_idxs),
-        like(program.inv_perm),
+        jax.tree.map(like, program.inv_perm),
         _spec((W,), one_chip),
         jax.tree.map(like, program.sels),
         extras,
         jax.tree.map(like, program.cse_idxs),
     )
+
+
+def _entry_ops(hlo: str) -> list[str]:
+    entry = hlo[hlo.index("\nENTRY") :]
+    entry = entry[: entry.index("\n}")]
+    return [ln.strip() for ln in entry.splitlines()[1:] if " = " in ln]
+
+
+def test_retention_flush_senses_in_place(one_chip):
+    """The flush of the benchmark's ``bmi.retention-cold`` cell (one
+    dashboard of 8 COUNT panels), built through the benchmark's adapter at
+    a small row count and compiled at the cell's page width: both sensing
+    steps read the store in place, so the program neither gathers, splits
+    nor splices the store, and its entry stays a handful of ops."""
+    from bench import traffic
+    from bench.harness import load_cell
+    from bench.system import System
+
+    cell = load_cell("bmi.retention-cold")
+    cfg = cell.config
+    rng = traffic.streams(3100000201)
+    table = cell.generator.make_table(cfg, rng["table"], 1 << 16)
+    fixed = np.random.default_rng(0)
+    placement = [traffic.resolve(p, {}, fixed) for p in cfg["placement_warmup"]]
+    system = System(cfg, table, placement)
+    for agg, pred in traffic.draw_request(cell.mix, rng["warm"]):
+        system.submit(agg, pred)
+    system.flush()
+    program = system.sched.last_program
+    assert program.sensings == (16, 0)
+
+    planes = int(cfg["planes"])
+    words = num_words(int(cfg["users"]) + int(cfg["reserve_rows"]))
+    words = -(-words // planes) * planes
+    sense, reduce_sigs, _, cse = program.key
+    runner = make_flush_runner((sense, reduce_sigs, words, cse), False)
+
+    def like(a):
+        return _spec(a.shape, one_chip, a.dtype)
+
+    store = (system.sched.device.store.num_slots, _page_rows(words), 128)
+    compiled = _compile_for_chip(
+        runner,
+        _spec(store, one_chip),
+        jax.tree.map(like, program.group_idxs),
+        jax.tree.map(like, program.inv_perm),
+        _spec((words,), one_chip),
+        jax.tree.map(like, program.sels),
+        tuple(None for _ in program.extras),
+        jax.tree.map(like, program.cse_idxs),
+    )
+    ops = _entry_ops(compiled.as_text())
+    assert len(ops) < 40, len(ops)
+    assert not [op for op in ops if "dynamic-update-slice" in op]
+    shapes = [f"[{store[0]},{store[1]},{store[2]}]", f"[{store[0]},{store[1] * 128}]"]
+    big = [
+        op
+        for op in ops
+        if "parameter(" not in op
+        and any(re.search(r"= \S+" + re.escape(sh), op) for sh in shapes)
+    ]
+    assert not big, big[:3]
+    assert sum("flashql_mws_sense" in op.split(" = ")[0] for op in ops) == 2
